@@ -55,9 +55,33 @@ ADAPTIVE_SHAPE = (17, 14, 12)
 ADAPTIVE_CHAIN_EVOS = ("advect", "diffuse")
 
 
-def compute_hashes() -> tuple[dict, list[str]]:
-    """-> ({case: sha256}, [cross-solver violations])."""
+def _round_trip(case, fields, decoded, bound_eb):
+    """Problems of a case whose fields decode to ``decoded``."""
+    problems = []
+    for t, (f, y) in enumerate(zip(fields, decoded)):
+        bound = bound_eb * (float(f.max()) - float(f.min()))
+        err = float(np.abs(f.astype(np.float64)
+                           - y.astype(np.float64)).max())
+        if err > bound:
+            where = f"frame {t} " if len(fields) > 1 else ""
+            problems.append(f"{case}: {where}round-trip error {err:.3e} "
+                            f"exceeds bound {bound:.3e}")
+    return problems
+
+
+def _solver_problems(case, blobs):
+    ref = blobs[SOLVERS[0]]
+    return [f"{case}: solver {s} bytes differ from {SOLVERS[0]} "
+            "(schedule independence broken)"
+            for s, b in blobs.items() if b != ref]
+
+
+def cases():
+    """Yield ``(case, dtype, run)`` for every manifest case, in manifest
+    order; ``run()`` compresses with every solver and returns
+    ``(container bytes, [problems])``."""
     from repro import engine, temporal
+    from repro.core.bitstream import EB_LADDER_K_MAX
     from repro.data.fields import (
         FIELD_GENERATORS,
         SEQUENCE_EVOLUTIONS,
@@ -65,32 +89,33 @@ def compute_hashes() -> tuple[dict, list[str]]:
         make_scientific_field,
     )
 
-    hashes = {}
-    problems = []
+    loose = 2.0 ** EB_LADDER_K_MAX
+
+    def snapshot(case, x, bound_eb, **kw):
+        def run():
+            blobs = {s: engine.compress(x, EB, solver=s, **kw)
+                     for s in SOLVERS}
+            ref = blobs[SOLVERS[0]]
+            return ref, (_solver_problems(case, blobs) + _round_trip(
+                case, [x], [engine.decompress(ref)], bound_eb))
+        return run
+
+    def chain(case, frames, bound_eb, **kw):
+        def run():
+            blobs = {s: temporal.compress_chain(
+                frames, EB, solver=s, keyframe_interval=CHAIN_INTERVAL, **kw)
+                for s in SOLVERS}
+            ref = blobs[SOLVERS[0]]
+            return ref, (_solver_problems(case, blobs) + _round_trip(
+                case, frames, temporal.decompress_chain(ref), bound_eb))
+        return run
+
     for name in sorted(FIELD_GENERATORS):
         for shape in SHAPES:
             for dtype in DTYPES:
                 x = make_scientific_field(name, shape, np.dtype(dtype), seed=5)
                 case = f"{name}/{'x'.join(map(str, shape))}/{dtype}"
-                blobs = {s: engine.compress(x, EB, solver=s) for s in SOLVERS}
-                ref = blobs[SOLVERS[0]]
-                for s, b in blobs.items():
-                    if b != ref:
-                        problems.append(
-                            f"{case}: solver {s} bytes differ from "
-                            f"{SOLVERS[0]} (schedule independence broken)"
-                        )
-                y = engine.decompress(ref)
-                bound = EB * (float(x.max()) - float(x.min()))
-                err = float(np.abs(x.astype(np.float64)
-                                   - y.astype(np.float64)).max())
-                if err > bound:
-                    problems.append(
-                        f"{case}: round-trip error {err:.3e} exceeds "
-                        f"bound {bound:.3e}"
-                    )
-                hashes[case] = hashlib.sha256(ref).hexdigest()
-
+                yield case, dtype, snapshot(case, x, EB)
     for evo in sorted(SEQUENCE_EVOLUTIONS):
         for base in CHAIN_BASES:
             for dtype in DTYPES:
@@ -98,89 +123,32 @@ def compute_hashes() -> tuple[dict, list[str]]:
                                              CHAIN_FRAMES, np.dtype(dtype),
                                              seed=5)
                 case = f"chain/{evo}/{base}/{dtype}"
-                blobs = {
-                    s: temporal.compress_chain(
-                        frames, EB, solver=s,
-                        keyframe_interval=CHAIN_INTERVAL)
-                    for s in SOLVERS
-                }
-                ref = blobs[SOLVERS[0]]
-                for s, b in blobs.items():
-                    if b != ref:
-                        problems.append(
-                            f"{case}: solver {s} bytes differ from "
-                            f"{SOLVERS[0]} (schedule independence broken)"
-                        )
-                decoded = temporal.decompress_chain(ref)
-                for t, f in enumerate(frames):
-                    bound = EB * (float(f.max()) - float(f.min()))
-                    err = float(np.abs(f.astype(np.float64)
-                                       - decoded[t].astype(np.float64)).max())
-                    if err > bound:
-                        problems.append(
-                            f"{case}: frame {t} round-trip error {err:.3e} "
-                            f"exceeds bound {bound:.3e}"
-                        )
-                hashes[case] = hashlib.sha256(ref).hexdigest()
-
-    from repro.core.bitstream import EB_LADDER_K_MAX
-
-    loose = 2.0 ** EB_LADDER_K_MAX
+                yield case, dtype, chain(case, frames, EB)
     for name in sorted(FIELD_GENERATORS):
         for dtype in DTYPES:
             x = make_scientific_field(name, ADAPTIVE_SHAPE,
                                       np.dtype(dtype), seed=5)
             case = f"adaptive/{name}/{dtype}"
-            blobs = {s: engine.compress(x, EB, solver=s, adaptive_eb="tda")
-                     for s in SOLVERS}
-            ref = blobs[SOLVERS[0]]
-            for s, b in blobs.items():
-                if b != ref:
-                    problems.append(
-                        f"{case}: solver {s} bytes differ from "
-                        f"{SOLVERS[0]} (schedule independence broken)"
-                    )
-            y = engine.decompress(ref)
-            bound = EB * loose * (float(x.max()) - float(x.min()))
-            err = float(np.abs(x.astype(np.float64)
-                               - y.astype(np.float64)).max())
-            if err > bound:
-                problems.append(
-                    f"{case}: round-trip error {err:.3e} exceeds "
-                    f"ladder bound {bound:.3e}"
-                )
-            hashes[case] = hashlib.sha256(ref).hexdigest()
-
+            yield case, dtype, snapshot(case, x, EB * loose,
+                                        adaptive_eb="tda")
     for evo in sorted(ADAPTIVE_CHAIN_EVOS):
         for dtype in DTYPES:
             frames = make_field_sequence(evo, "gaussians", ADAPTIVE_SHAPE,
                                          CHAIN_FRAMES, np.dtype(dtype),
                                          seed=5)
             case = f"chain-adaptive/{evo}/{dtype}"
-            blobs = {
-                s: temporal.compress_chain(
-                    frames, EB, solver=s, keyframe_interval=CHAIN_INTERVAL,
-                    adaptive_eb="tda")
-                for s in SOLVERS
-            }
-            ref = blobs[SOLVERS[0]]
-            for s, b in blobs.items():
-                if b != ref:
-                    problems.append(
-                        f"{case}: solver {s} bytes differ from "
-                        f"{SOLVERS[0]} (schedule independence broken)"
-                    )
-            decoded = temporal.decompress_chain(ref)
-            for t, f in enumerate(frames):
-                bound = EB * loose * (float(f.max()) - float(f.min()))
-                err = float(np.abs(f.astype(np.float64)
-                                   - decoded[t].astype(np.float64)).max())
-                if err > bound:
-                    problems.append(
-                        f"{case}: frame {t} round-trip error {err:.3e} "
-                        f"exceeds ladder bound {bound:.3e}"
-                    )
-            hashes[case] = hashlib.sha256(ref).hexdigest()
+            yield case, dtype, chain(case, frames, EB * loose,
+                                     adaptive_eb="tda")
+
+
+def compute_hashes() -> tuple[dict, list[str]]:
+    """-> ({case: sha256}, [cross-solver and round-trip violations])."""
+    hashes = {}
+    problems = []
+    for case, _, run in cases():
+        blob, found = run()
+        hashes[case] = hashlib.sha256(blob).hexdigest()
+        problems += found
     return hashes, problems
 
 

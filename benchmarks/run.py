@@ -17,6 +17,9 @@ def main() -> None:
                          "service|temporal|store|cluster|quality|roofline")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (  # noqa: WPS433
         cluster_bench,
         engine_bench,

@@ -40,6 +40,7 @@ import threading
 import time
 from pathlib import Path
 
+import jax
 import numpy as np
 
 from .. import engine as _engine
@@ -588,15 +589,36 @@ class LocalCluster:
         self.close()
 
 
+def one_process_per_chip() -> bool:
+    """Is the backend a TPU, whose chip belongs to one process at a time?
+
+    Every shard worker (and the router, which compresses in this
+    process) would need the chip, so shards then run in-process
+    (:class:`LocalCluster`) and :class:`ProcessCluster` refuses.
+    """
+    return jax.default_backend() == "tpu"
+
+
+def refuse_on_tpu() -> None:
+    """Raise before worker processes are spawned onto a TPU host."""
+    if one_process_per_chip():
+        raise RuntimeError(
+            "ProcessCluster starts one JAX process per shard, but a TPU "
+            "chip belongs to one process at a time: run the shards "
+            "in-process with LocalCluster on a TPU host")
+
+
 class ProcessCluster:
     """N subprocess workers (``python -m repro.cluster.worker``) behind
     ``SocketTransport``s — the deployment shape ``launch/serve.py
-    --cluster`` drives.  ``kill(i)`` SIGKILLs the worker process."""
+    --cluster`` drives off the chip.  ``kill(i)`` SIGKILLs the worker
+    process.  Refused on a TPU backend (:func:`refuse_on_tpu`)."""
 
     def __init__(self, root, n_shards: int, *,
                  plan: CompressionPlan | None = None, n_replicas: int = 2,
                  cache_bytes: int | None = None, spawn_timeout: float = 120.0,
                  env: dict | None = None, **router_kw):
+        refuse_on_tpu()
         root = Path(root)
         plan = plan or CompressionPlan()
         self.procs: list[subprocess.Popen] = []

@@ -27,6 +27,7 @@ from pathlib import Path
 import numpy as np
 
 from .. import obs as _obs
+from ..compile_cache import enable_compile_cache
 from ..engine.plan import CompressionPlan
 from ..service import CompressionService, ServiceConfig
 from ..store import LopcStore
@@ -234,6 +235,7 @@ def main(argv=None) -> int:
     ap.add_argument("--batch-tiles", type=int, default=None)
     ap.add_argument("--cache-bytes", type=int, default=DEFAULT_CACHE_BYTES)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     plan = None
     if args.tile_shape is not None or args.batch_tiles is not None:
         kw = {}

@@ -31,7 +31,7 @@ from . import bitstream
 from .quantize import (
     abs_bound_from_mode,
     bin_dtype_for,
-    check_bin_range,
+    check_eps,
     dequantize,
     quantize,
 )
@@ -134,13 +134,7 @@ def _compress_v1(field, eb, mode, preserve_order, solver, return_stats):
         x, nonfinite_payload = encode_nonfinite(x)
 
     eps_abs = abs_bound_from_mode(x, eb, mode)
-    if eps_abs < float(np.finfo(x.dtype).tiny):
-        raise ValueError(
-            f"error bound {eps_abs:.3e} is below the smallest normal "
-            f"{x.dtype} ({np.finfo(x.dtype).tiny:.3e}); XLA flushes "
-            "denormals (FTZ), so sub-denormal bin widths cannot be honored"
-        )
-    check_bin_range(x, eps_abs)
+    check_eps(x, eps_abs)
 
     xj = jnp.asarray(x)
     bins = quantize(xj, eps_abs)

@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .. import engine
 
@@ -52,12 +52,20 @@ def make_tile_put(mesh, axis: str = "data"):
     tile counts land on multiples of the axis size to split every batch.
     """
     n = mesh.shape[axis]
+    # ``jax.make_mesh`` gives Explicit axes, whose sharding-in-types rules
+    # refuse the executor's pads and halo gather on a sharded tile axis;
+    # Auto axes leave placement to the compiler, which is all we ask.
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
     def put(a):
         a = jnp.asarray(a)
         spec = P(axis) if (a.ndim >= 1 and a.shape[0] % n == 0) else P()
         return jax.device_put(a, NamedSharding(mesh, spec))
 
+    # the executor traces its programs under this mesh (``jax.set_mesh``),
+    # which is how the Mosaic kernels learn to run per device
+    put.mesh = mesh
     return put
 
 

@@ -28,10 +28,10 @@ output bytes are identical — the paper's schedule independence, §IV-E):
              (kernels/subbin_sweep.solve_tiles_blockwise); lowers via
              Mosaic on TPU, runs in interpret mode elsewhere
 
-Per-tile error bounds ride along as a (C,) f64 operand (broadcast to
-(C,1,1,1) inside), so one traced program serves tiles of *different
-fields with different bounds* in the same resident batch — the core of
-``compress_many``'s request coalescing.
+Per-tile error bounds ride along as a (C,) :class:`~repro.core.quantize.Eps`
+operand (broadcast to (C,1,1,1) inside), so one traced program serves
+tiles of *different fields with different bounds* in the same resident
+batch — the core of ``compress_many``'s request coalescing.
 """
 from __future__ import annotations
 
@@ -46,8 +46,8 @@ from ..codecs.bitshuffle import bitshuffle, bitunshuffle
 from ..codecs.rze import rze_bitmap, rze_decode
 from ..codecs.transforms import delta_decode, delta_encode, zigzag_decode, zigzag_encode
 from ..core import topology
-from ..core.floatbits import float_to_ordered, int_dtype_for, ordered_to_float
-from ..core.quantize import decode_base, quantize_broadcast
+from ..core.floatbits import int_dtype_for, ordered_to_float
+from ..core.quantize import Eps, decode_base_ordered, eps_operand, quantize_broadcast
 
 # Incremented inside traced function bodies: Python side effects run only
 # while tracing, so this counts jit traces, not executions.  Tests use it
@@ -235,9 +235,9 @@ def _resident_solve(flags, idx_m, mask_m, solver: str, interpret: bool,
     return final, local1, last_round
 
 
-def _quantize_halo(x_h: jnp.ndarray, eps_b: jnp.ndarray, dtype) -> jnp.ndarray:
-    """core.quantize._quantize_impl with a per-tile broadcast eps."""
-    return quantize_broadcast(x_h, eps_b, dtype)
+def put_eps(put, eps: np.ndarray) -> Eps:
+    """Upload per-tile f64 bounds as the device's :class:`Eps` operand."""
+    return Eps(*(put(a) for a in eps_operand(eps)))
 
 
 # ------------------------------------------------ lossless stage (shared)
@@ -323,8 +323,7 @@ def _resident_quantize(x_h, eps, dtype, preserve_order: bool):
     TRACE_COUNTS["resident_quantize"] += 1
     valid_h = jnp.isfinite(x_h)
     x0 = jnp.where(valid_h, x_h, jnp.asarray(0, x_h.dtype))
-    eps_b = eps[:, None, None, None]
-    bins_h = _quantize_halo(x0, eps_b, dtype)
+    bins_h = quantize_broadcast(x0, eps.expand(3), dtype)
     sentinel = jnp.iinfo(bins_h.dtype).min
     bins_h = jnp.where(valid_h, bins_h, sentinel)
     bins_enc = jnp.where(_interior(valid_h), _interior(bins_h), 0)
@@ -398,11 +397,11 @@ def _resident_quantize_adaptive(x_h, eps, dtype):
     TRACE_COUNTS["resident_quantize_adaptive"] += 1
     valid_h = jnp.isfinite(x_h)
     x0 = jnp.where(valid_h, x_h, jnp.asarray(0, x_h.dtype))
-    eps_b = eps[:, None, None, None]
-    bins_h = _quantize_halo(x0, eps_b, dtype)
+    eps_b = eps.expand(3)
+    bins_h = quantize_broadcast(x0, eps_b, dtype)
     bins_enc = jnp.where(_interior(valid_h), _interior(bins_h), 0)
-    base = decode_base(_interior(bins_h), eps_b, dtype)
-    u_init = _bias_ordered(float_to_ordered(base))
+    u_init = _bias_ordered(decode_base_ordered(_interior(bins_h), eps_b,
+                                               dtype))
     u_init = jnp.where(_interior(valid_h), u_init,
                        jnp.asarray(0, u_init.dtype))
     vals_m = _merge(jnp.where(valid_h, x0, jnp.asarray(jnp.inf, x0.dtype)))
@@ -478,7 +477,7 @@ def encode_tiles_fused(ints, chunk_len: int, transform: str):
          static_argnames=("dtype", "bins_store", "bins_chunk", "interpret"))
 def _fused_encode_values_program(x_h, eps, dtype, bins_store,
                                  bins_chunk: int, interpret: bool):
-    TRACE_COUNTS["fused_encode"] += 1
+    TRACE_COUNTS["fused_encode_values"] += 1
     from ..kernels.fused_encode import encode_values_fused
 
     capacity = x_h.shape[0]
@@ -489,10 +488,11 @@ def _fused_encode_values_program(x_h, eps, dtype, bins_store,
 
 def resident_encode_fused(x_h, eps, dtype, bins_store, bins_chunk: int):
     """Full compress fusion for the plain (preserve_order=False) f32
-    path: NaN-validity -> quantize -> delta/zigzag -> BIT -> RZE-bitmap
-    as ONE Pallas kernel over the haloed tile batch.  Quantize is the
-    shared ``quantize_broadcast`` op sequence, so the bins — and hence
-    the streams — equal the staged frontend's bit-for-bit."""
+    path with 16-bit bins: NaN-validity -> quantize -> delta/zigzag ->
+    BIT -> RZE-bitmap as ONE Pallas kernel over the haloed tile batch.
+    Quantize is the shared ``quantize_broadcast`` op sequence, so the
+    bins — and hence the streams — equal the staged frontend's
+    bit-for-bit."""
     _, interpret = resolve_solver("auto")
     return _fused_encode_values_program(x_h, eps, jnp.dtype(dtype),
                                         jnp.dtype(bins_store), bins_chunk,
@@ -566,8 +566,11 @@ def resident_compress(x_h, eps, idx, mask, max_rounds, dtype,
     stage.
     """
     capacity = x_h.shape[0]
+    # the fused quantize guesses in f32 (Mosaic has no f64), which lands
+    # on the exact bins only while |bin| < 2**14: 16-bit streams
     if (encode_fused and not preserve_order
-            and jnp.dtype(dtype) == jnp.float32):
+            and jnp.dtype(dtype) == jnp.float32
+            and jnp.dtype(bins_store).itemsize == 2):
         bins_streams = resident_encode_fused(x_h, eps, dtype, bins_store,
                                              bins_chunk)
         zc = jnp.zeros((capacity,), jnp.int32)
@@ -648,16 +651,15 @@ def dequantize_tiles(bins, subbins, eps, dtype):
     """(C, E) resident bins+subbins -> reconstructed values, per-tile
     eps (mirroring the compress side's per-tile bounds)."""
     TRACE_COUNTS["dequantize"] += 1
-    eps_b = eps[:, None]
-    base = decode_base(bins, eps_b, dtype)
+    base = decode_base_ordered(bins, eps.expand(1), dtype)
     idt = int_dtype_for(dtype)
     # an adaptive f32 subbin stream can be wider than f32's ordered-int
     # width (ordered distances across a zero-straddling bin): accumulate
     # in the wider type — the final ordered value always fits idt
     if jnp.dtype(subbins.dtype).itemsize > jnp.dtype(idt).itemsize:
-        o = float_to_ordered(base).astype(subbins.dtype) + subbins
+        o = base.astype(subbins.dtype) + subbins
         return ordered_to_float(o.astype(idt), dtype)
-    return ordered_to_float(float_to_ordered(base) + subbins.astype(idt), dtype)
+    return ordered_to_float(base + subbins.astype(idt), dtype)
 
 
 def _signed_twin(arr) -> jnp.dtype:

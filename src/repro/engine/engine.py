@@ -40,7 +40,8 @@ from ..core.lopc import CompressStats, decode_nonfinite, encode_nonfinite
 from ..core.quantize import (
     abs_bound_from_mode,
     bin_dtype_for,
-    check_bin_range,
+    check_backend,
+    check_eps,
     effective_eps,
 )
 from . import device
@@ -99,16 +100,6 @@ def _validate(x: np.ndarray, eb: float):
         raise ValueError("error bound must be positive")
 
 
-def _check_eps(x: np.ndarray, eps_abs: float):
-    if eps_abs < float(np.finfo(x.dtype).tiny):
-        raise ValueError(
-            f"error bound {eps_abs:.3e} is below the smallest normal "
-            f"{x.dtype} ({np.finfo(x.dtype).tiny:.3e}); XLA flushes "
-            "denormals (FTZ), so sub-denormal bin widths cannot be honored"
-        )
-    check_bin_range(x, eps_abs)
-
-
 # -------------------------------------------------------------- compress
 
 class _Request:
@@ -125,7 +116,7 @@ class _Request:
         self.mode = mode
         self.adaptive = adaptive_eb == "tda"
         self.eps_abs = abs_bound_from_mode(x, eb, mode)
-        _check_eps(x, self.eps_abs)  # the tightest rung is the user bound
+        check_eps(x, self.eps_abs)  # the tightest rung is the user bound
         self.layout = plan.layout_for(x.shape)
         self.ladder = None
         if self.adaptive:
@@ -461,6 +452,8 @@ def _decode_runs(runs, plan, group_cb=None, decode_path: str = "auto"):
         np.empty((0,) + tuple(layout.tile), np.dtype(c.header.dtype))
         for c, layout, _ in runs
     ]
+    for dtype, _, _, _ in groups:
+        check_backend(dtype, "decompress")
     ex = default_executor(plan, "auto", decode_path)
     for (dtype, tile, order, words), members in groups.items():
         if group_cb is not None:

@@ -50,8 +50,9 @@ steady state adds nothing.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -150,6 +151,27 @@ def use_fused_encode(encode_path: str, padded_elems: int,
     return not interpret and padded_elems >= FUSED_ENCODE_AUTO_MIN_ELEMS
 
 
+def stream_encoder(encode_path: str, fused: bool, interpret: bool):
+    """-> ``encode(ints, chunk_len, transform)`` for a group's streams.
+
+    The fused kernel has no 64-bit form on the chip (Mosaic has no
+    64-bit vector types): under ``auto`` an 8-byte stream there takes
+    the staged encode, whose outputs the compacted download takes
+    alike; an explicit ``fused`` request gets the compiler's refusal.
+    """
+    if not fused:
+        return device.encode_tiles
+    if interpret or encode_path == "fused":
+        return device.encode_tiles_fused
+
+    def encode(ints, chunk_len: int, transform: str):
+        wide = jnp.dtype(ints.dtype).itemsize == 8
+        return (device.encode_tiles if wide else device.encode_tiles_fused)(
+            ints, chunk_len, transform)
+
+    return encode
+
+
 def reset_transfer_counts() -> None:
     TRANSFER_COUNTS.clear()
 
@@ -236,6 +258,9 @@ class Executor:
         self.decode_path = decode_path
         self.encode_path = encode_path
         self.put = put or (lambda a: jnp.asarray(a))
+        mesh = getattr(put, "mesh", None)
+        self._placement = (partial(jax.set_mesh, mesh) if mesh is not None
+                           else contextlib.nullcontext)
 
     # ------------------------------------------------------------ compress
 
@@ -255,6 +280,12 @@ class Executor:
         share a group.  Exactly one tile upload and one stream download
         happen here, whatever the solver or round count.
         """
+        with self._placement():
+            return self._compress_tiles(x_tiles, eps_tiles, layouts, dtype,
+                                        preserve_order, bins_store, adaptive)
+
+    def _compress_tiles(self, x_tiles, eps_tiles, layouts, dtype,
+                        preserve_order, bins_store, adaptive) -> GroupStreams:
         layout0 = layouts[0]
         n_total = x_tiles.shape[0]
         floor = max(CAPACITY_FLOOR, self.plan.batch_tiles)
@@ -292,7 +323,7 @@ class Executor:
             with span("exec.upload", tiles=n_chunk, capacity=capacity,
                       nbytes=xc.nbytes):
                 x_dev = self.put(xc)
-                eps_dev = self.put(ec)
+                eps_dev = device.put_eps(self.put, ec)
                 idx_dev = self.put(idx)
                 mask_dev = self.put(mask)
                 fence(x_dev, eps_dev, idx_dev, mask_dev)
@@ -331,8 +362,7 @@ class Executor:
                 # straddling zero at a huge eb) can exceed int32
                 sub_store = np.dtype(np.int64)
             subs_cpt, subs_chunk = chunks_per_tile(layout0, sub_store)
-            encode = device.encode_tiles_fused if fused else \
-                device.encode_tiles
+            encode = stream_encoder(self.encode_path, fused, interpret)
             with span("exec.encode", chunks=len(chunks), fused=fused,
                       sub_word=sub_store.itemsize):
                 for c in chunks:
@@ -476,19 +506,20 @@ class Executor:
                 out = device.resident_decode_fused(
                     self.put(bitmap), self.put(packed),
                     self.put(sub_bitmap), self.put(sub_packed),
-                    self.put(eps), tile_elems=tile_elems,
+                    device.put_eps(self.put, eps), tile_elems=tile_elems,
                     dtype=jnp.dtype(dtype),
                 )
             elif order:
                 out = device.resident_decode_order(
                     self.put(bitmap), self.put(packed),
                     self.put(sub_bitmap), self.put(sub_packed),
-                    self.put(eps), tile_elems=tile_elems,
+                    device.put_eps(self.put, eps), tile_elems=tile_elems,
                     dtype=jnp.dtype(dtype),
                 )
             else:
                 out = device.resident_decode_plain(
-                    self.put(bitmap), self.put(packed), self.put(eps),
+                    self.put(bitmap), self.put(packed),
+                    device.put_eps(self.put, eps),
                     tile_elems=tile_elems, dtype=jnp.dtype(dtype),
                 )
             fence(out)

@@ -5,22 +5,15 @@ Two entry points share the file:
 
 ``decode_tiles_fused``
     The engine's fused decompress backend (``decode_path="fused"``):
-    RZE-expand -> bitshuffle-undo -> dezigzag/undelta -> dequantize in
-    ONE kernel, gridded over tile blocks.  On a TPU each grid step
-    touches one tile's chunk rows (~16 KiB per stream) and writes its
-    values; in interpret mode the whole batch rides one grid step (one
-    dispatch instead of the staged chain's three, with the full decode
-    chain fused into a single XLA computation).  Bit-for-bit identity
-    with the staged chain is
-    free by construction: the kernel body calls the *same* codec and
-    quantize functions (``rze_decode``, ``bitunshuffle``,
-    ``zigzag_decode``/``delta_decode``, ``decode_base``, ordered-int
-    float walk) the stage programs call, all of which are integer-exact
-    or contractually f32-deterministic; tests pin it against the
-    determinism manifest.  f32 only — f64 decode stays on the staged
-    chain (its base math is x64-config-dependent in exactly the way the
-    shared ``decode_base`` encodes, but the fused path has no need to
-    cover a cold case).
+    RZE-expand -> bitshuffle-undo -> dezigzag/undelta -> dequantize as
+    one jitted program.  The word-level steps run as a Pallas kernel in
+    the bit-plane layout of ``kernels.planes`` (one bit-matrix transpose
+    undoes BIT_w, a rotate-and-add scan undoes the delta), gridded over
+    chunk blocks; the RZE expansion (a gather) and the exact-integer
+    dequantize run in XLA around it.  Every step is integer-exact, so
+    values equal the staged chain's bit for bit; tests pin it against
+    the determinism manifest.  The executor routes f32 ordered decode
+    with <= 4-byte sections here; other cases stay staged.
 
 ``dequantize_ff32``
     The original FF32-contract dequantize microkernel (reconstruct =
@@ -38,11 +31,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..codecs.bitshuffle import bitunshuffle
 from ..codecs.rze import rze_decode
-from ..codecs.transforms import delta_decode, zigzag_decode
-from ..core.floatbits import float_to_ordered, int_dtype_for, ordered_to_float
-from ..core.quantize import decode_base
+from ..core.floatbits import int_dtype_for, ordered_to_float
+from ..core.quantize import Eps, decode_base_ordered
+from . import planes
 
 LANE = 128
 BLOCK_ROWS = 256
@@ -50,77 +42,49 @@ BLOCK_ROWS = 256
 
 # ------------------------------------------------- fused decode pipeline
 
-def _expand_ints(bitmap, packed, n_tiles: int, tile_elems: int,
-                 transform: str):
-    """One block's section rows -> (n_tiles, tile_elems) signed ints.
-
-    Op-for-op the stage programs' ``_decode_ints``: every call here is
-    the same function the staged chain jits, so the integers match
-    bit-for-bit.
-    """
-    shuffled = rze_decode(bitmap, packed)
-    words = bitunshuffle(shuffled)
+def _inverse(a, w: int, transform: str):
+    """Bit-plane rows of one stream -> sign-extended signed ints."""
+    words = planes.bit_transpose(a, w)
     if transform == "delta":
-        chunks = delta_decode(zigzag_decode(words))
-    else:  # "raw"
-        chunks = words.astype(jnp.dtype(words.dtype.str.replace("u", "i")))
-    rows, chunk_len = chunks.shape
-    cpt = rows // n_tiles
-    return chunks.reshape(n_tiles, cpt * chunk_len)[:, :tile_elems]
+        return planes.sign_extend(planes.running_sum(
+            planes.sign_extend(planes.unzigzag(words), w)), w)
+    return planes.sign_extend(words, w)   # "raw"
 
 
-def decode_tiles_fused(bitmap, packed, sub_bitmap, sub_packed, eps,
-                       tile_elems: int, dtype, interpret: bool = False,
-                       block_tiles: int | None = None):
+def decode_stream(bitmap, packed, batch: int, tile_elems: int,
+                  transform: str, interpret: bool):
+    """(batch * cpt, ...) RZE section rows -> (batch, tile_elems) ints in
+    the container type (int32 for <= 4-byte words)."""
+    shuffled = rze_decode(bitmap, packed)
+    n, length = shuffled.shape
+    w = shuffled.dtype.itemsize * 8
+    cdt = planes.container(w)
+    if cdt.itemsize == shuffled.dtype.itemsize:
+        m = shuffled.view(cdt)
+    else:
+        m = shuffled.astype(cdt)
+    out = planes.plane_call(lambda a: _inverse(a, w, transform),
+                            m.reshape(n, w, length // w), [], cdt,
+                            interpret)
+    return planes.from_planes(out, batch, tile_elems)
+
+
+def decode_tiles_fused(bitmap, packed, sub_bitmap, sub_packed, eps: Eps,
+                       tile_elems: int, dtype, interpret: bool = False):
     """Fused ordered decode of a tile batch -> (batch, tile_elems).
 
     Inputs mirror ``device.resident_decode_order``: RZE sections as
     (batch * cpt, ...) bitmap/packed word arrays (bins delta-coded,
-    subbins raw), per-tile ``eps`` riding SMEM.  ``block_tiles`` sets
-    the grid granularity — tiles per kernel invocation.  Default: the
-    whole batch in interpret mode (one dispatch; the grid loop would
-    serialize work XLA otherwise threads across the batch) and one tile
-    per step on real TPUs (grid parallelism, ~16 KiB VMEM blocks per
-    stream).  Batch capacities are bucket classes (``engine.buckets``),
-    so any pow2 ``block_tiles`` divides them.
+    subbins raw) and the per-tile :class:`~repro.core.quantize.Eps`.
     """
     dtype = jnp.dtype(dtype)
-    batch = eps.shape[0]
-    if block_tiles is None:
-        block_tiles = batch if interpret else 1
-    if batch % block_tiles:
-        raise ValueError(f"block_tiles {block_tiles} must divide {batch}")
-    bins_cpt = bitmap.shape[0] // batch
-    subs_cpt = sub_bitmap.shape[0] // batch
-    idt = int_dtype_for(dtype)
-
-    def kernel(eps_ref, bm_ref, pk_ref, sbm_ref, spk_ref, out_ref):
-        bins = _expand_ints(bm_ref[...], pk_ref[...], block_tiles,
-                            tile_elems, "delta")
-        subs = _expand_ints(sbm_ref[...], spk_ref[...], block_tiles,
-                            tile_elems, "raw")
-        base = decode_base(bins, eps_ref[...][:, None], dtype)
-        out_ref[...] = ordered_to_float(
-            float_to_ordered(base) + subs.astype(idt), dtype
-        )
-
-    def rows(arr, cpt):
-        return pl.BlockSpec((block_tiles * cpt, arr.shape[1]),
-                            lambda i: (i, 0))
-
-    return pl.pallas_call(
-        kernel,
-        grid=(batch // block_tiles,),
-        in_specs=[
-            pl.BlockSpec((block_tiles,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-            rows(bitmap, bins_cpt), rows(packed, bins_cpt),
-            rows(sub_bitmap, subs_cpt), rows(sub_packed, subs_cpt),
-        ],
-        out_specs=pl.BlockSpec((block_tiles, tile_elems), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch, tile_elems), dtype),
-        interpret=interpret,
-    )(eps, bitmap, packed, sub_bitmap, sub_packed)
+    batch = eps.value.shape[0]
+    bins = decode_stream(bitmap, packed, batch, tile_elems, "delta",
+                         interpret)
+    subs = decode_stream(sub_bitmap, sub_packed, batch, tile_elems, "raw",
+                         interpret)
+    base = decode_base_ordered(bins, eps.expand(1), dtype)
+    return ordered_to_float(base + subs.astype(int_dtype_for(dtype)), dtype)
 
 
 # ------------------------------------------- FF32 dequantize microkernel

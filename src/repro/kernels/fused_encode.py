@@ -4,107 +4,72 @@
 Two entry points share the file:
 
 ``encode_ints_fused``
-    The lossless encode stage as ONE kernel: [delta ->]
-    [zigzag|reinterpret] -> BIT_w -> RZE-bitmap over a resident integer
-    batch, gridded over tile blocks.  Drives the bins stream after the
-    staged frontend (and the subs stream after the solve, and temporal
+    The lossless encode stage over a resident integer batch: [delta ->]
+    [zigzag|reinterpret] -> BIT_w in one kernel gridded over chunk
+    blocks, in the bit-plane layout of ``kernels.planes``, with the
+    RZE bitmap and counts taken from the kernel's shuffled words in the
+    same jitted program.  Drives the bins stream after the staged
+    frontend (and the subs stream after the solve, and temporal
     residual streams via the same ``transform`` modes the staged
-    ``encode_tiles`` takes).  On a TPU each grid step touches one tile's
-    integers and writes its chunk rows; in interpret mode the whole
-    batch rides one grid step — one dispatch instead of the staged
-    chain's separate transform/BIT/RZE programs.  Bit-for-bit identity
-    with the staged stage programs is free by construction: the kernel
-    body calls the *same* codec functions (``delta_encode``/
-    ``zigzag_encode``, ``bitshuffle``, ``rze_bitmap``) the stage
-    programs call, all integer-exact; tests pin it against the
-    determinism manifest.
+    ``encode_tiles`` takes).  Every step is integer-exact, so the
+    streams equal the staged stage programs' bit for bit; tests pin it
+    against the staged chain and the determinism manifest.
 
 ``encode_values_fused``
     The full compress fusion for the plain (preserve_order=False) f32
-    path: NaN-validity -> guaranteed-bound quantize -> delta/zigzag ->
-    BIT -> RZE-bitmap in one kernel.  Quantize math is the shared
-    ``quantize_broadcast`` op sequence, so bins equal the staged
-    frontend's bit-for-bit.  f32 only — f64 quantize is
-    x64-config-dependent in exactly the way the shared helper encodes,
-    and the ordered path needs the flags/solve stages between quantize
-    and encode anyway, so those cases run the staged frontend plus
-    ``encode_ints_fused``.
+    path with 16-bit bin streams: NaN-validity -> guaranteed-bound
+    quantize -> delta/zigzag -> BIT in the kernel.  Quantize is the
+    shared ``quantize_broadcast`` sequence with the exact integer decode
+    anchors.  Its first guess is an f32 quotient (Mosaic has no f64) on
+    every backend; a 16-bit stream means |bin| < 2**14, where that guess
+    is within one bin of the exact one, so the verify-and-correct pass
+    lands on the staged frontend's bins.
 
-Any row count works: batches pad internally to a ``block_tiles``
-multiple (pad rows encode as all-zero streams) and the outputs slice
-back — mirroring ``dequantize_ff32``'s padding fix rather than
-``decode_tiles_fused``'s divisibility requirement, because encode
-batches can arrive at odd sizes from callers outside the bucketed
-executor.
+Any row count works: chunk blocks pad internally (pad chunks encode as
+all-zero streams) and the outputs slice back, because encode batches
+can arrive at odd sizes from callers outside the bucketed executor.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ..codecs.bitshuffle import bitshuffle
 from ..codecs.rze import rze_bitmap
-from ..codecs.transforms import delta_encode, zigzag_encode
-from ..core.quantize import quantize_broadcast
+from ..core.quantize import Eps, quantize_broadcast
+from . import planes
 
 
 def _word_dtype(ints_dtype) -> jnp.dtype:
     return jnp.dtype(jnp.dtype(ints_dtype).str.replace("i", "u"))
 
 
-def _collapse_ints(ints, n_tiles: int, chunk_len: int, transform: str):
-    """One block's (n_tiles, E) ints -> (bitmap, shuffled, counts) rows.
-
-    Op-for-op the stage programs' ``_encode_ints``: every call here is
-    the same codec function the staged chain jits, so the streams match
-    bit-for-bit.
-    """
-    b, e = ints.shape
-    n_chunks = -(-e // chunk_len)
-    padded = jnp.pad(ints, ((0, 0), (0, n_chunks * chunk_len - e)))
-    chunks = padded.reshape(b * n_chunks, chunk_len)
+def _forward(m, w: int, transform: str):
+    """(n, w, Q) sign-extended ints -> bit-plane rows of the words."""
     if transform == "delta":
-        words = zigzag_encode(delta_encode(chunks))
+        words = planes.zigzag(
+            planes.sign_extend(m - planes.previous_element(m), w), w)
     elif transform == "zigzag":
-        words = zigzag_encode(chunks)
-    else:  # "raw"
-        words = chunks.astype(_word_dtype(chunks.dtype))
-    shuffled = bitshuffle(words)
-    bitmap, counts = rze_bitmap(shuffled)
-    return bitmap, shuffled, counts
+        words = planes.zigzag(m, w)
+    elif transform == "raw":
+        words = planes.low_bits(m, w)
+    else:
+        raise ValueError(f"unknown transform {transform!r}")
+    return planes.bit_transpose(words, w)
 
 
-def _encode_call(kernel, operands, specs, batch: int, pad: int,
-                 block_tiles: int, cpt: int, chunk_len: int, wdt,
-                 interpret: bool):
-    """Shared pallas_call plumbing of the two entry points: grid over
-    tile blocks, stream outputs as chunk rows, counts riding SMEM."""
-    w = jnp.dtype(wdt).itemsize * 8
-    padded = batch + pad
-    bitmap, packed, counts = pl.pallas_call(
-        kernel,
-        grid=(padded // block_tiles,),
-        in_specs=specs,
-        out_specs=[
-            pl.BlockSpec((block_tiles * cpt, chunk_len // w),
-                         lambda i: (i, 0)),
-            pl.BlockSpec((block_tiles * cpt, chunk_len), lambda i: (i, 0)),
-            pl.BlockSpec((block_tiles * cpt,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((padded * cpt, chunk_len // w), wdt),
-            jax.ShapeDtypeStruct((padded * cpt, chunk_len), wdt),
-            jax.ShapeDtypeStruct((padded * cpt,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    if pad:
-        k = batch * cpt
-        bitmap, packed, counts = bitmap[:k], packed[:k], counts[:k]
-    return bitmap, packed, counts
+def _streams(shuffled, batch: int, cpt: int, wdt):
+    """Kernel output -> (bitmap, shuffled words, counts) chunk rows."""
+    n = batch * cpt
+    words = shuffled[:n].reshape(n, -1)
+    if jnp.dtype(wdt).itemsize == words.dtype.itemsize:
+        words = words.view(wdt)
+    else:
+        words = words.astype(wdt)   # low 16 bits of int32 containers
+    bitmap, counts = rze_bitmap(words)
+    return bitmap, words, counts
+
+
+def _step(block_tiles, cpt: int):
+    return None if block_tiles is None else block_tiles * cpt
 
 
 def encode_ints_fused(ints, chunk_len: int, transform: str,
@@ -114,71 +79,54 @@ def encode_ints_fused(ints, chunk_len: int, transform: str,
     (bitmap, shuffled words, counts) chunk rows.
 
     Output shapes and values equal ``device.encode_tiles`` exactly.
-    ``block_tiles`` sets tiles per grid step — default the whole batch
-    in interpret mode (one dispatch) and one tile per step on real TPUs.
+    ``block_tiles`` sets tiles per grid step (default: the whole batch
+    in interpret mode, ``planes.CHUNKS_PER_STEP`` chunks on the chip).
     """
     batch, elems = ints.shape
-    if block_tiles is None:
-        block_tiles = batch if interpret else 1
-    pad = -batch % block_tiles
-    if pad:  # pad rows are all-zero ints -> all-zero streams, sliced off
-        ints = jnp.concatenate(
-            [ints, jnp.zeros((pad, elems), ints.dtype)])
+    w = jnp.dtype(ints.dtype).itemsize * 8
     cpt = -(-elems // chunk_len)
-    wdt = _word_dtype(ints.dtype)
-
-    def kernel(ints_ref, bm_ref, pk_ref, cnt_ref):
-        bitmap, shuffled, counts = _collapse_ints(
-            ints_ref[...], block_tiles, chunk_len, transform)
-        bm_ref[...] = bitmap
-        pk_ref[...] = shuffled
-        cnt_ref[...] = counts
-
-    specs = [pl.BlockSpec((block_tiles, elems), lambda i: (i, 0))]
-    return _encode_call(kernel, (ints,), specs, batch, pad, block_tiles,
-                        cpt, chunk_len, wdt, interpret)
+    m = planes.to_planes(ints, chunk_len, w)
+    out = planes.plane_call(lambda a: _forward(a, w, transform), m, [],
+                            planes.container(w), interpret,
+                            _step(block_tiles, cpt))
+    return _streams(out, batch, cpt, _word_dtype(ints.dtype))
 
 
-def encode_values_fused(x_int, eps, chunk_len: int, dtype, bins_store,
+def encode_values_fused(x_int, eps: Eps, chunk_len: int, dtype, bins_store,
                         interpret: bool = False,
                         block_tiles: int | None = None):
     """Fused full encode of (batch, E) NaN-marked f32 interiors ->
     the bins stream's (bitmap, shuffled words, counts).
 
     NaN cells (tile pad, pad tiles) encode as bin 0 exactly like the
-    staged frontend's validity masking; ``eps`` is the per-tile bound
-    riding SMEM.  Only valid for preserve_order=False float32 batches
-    (see module docstring).
+    staged frontend's validity masking; ``eps`` is the per-tile
+    :class:`~repro.core.quantize.Eps`.  Only valid for
+    preserve_order=False float32 batches whose bins the host stores in
+    16 bits (see module docstring).
     """
     dtype = jnp.dtype(dtype)
     bins_store = jnp.dtype(bins_store)
+    if bins_store.itemsize != 2:
+        raise ValueError(f"fused values encode needs 16-bit bins, got "
+                         f"{bins_store}: the f32 first guess is exact "
+                         "only for |bin| < 2**14")
     batch, elems = x_int.shape
-    if block_tiles is None:
-        block_tiles = batch if interpret else 1
-    pad = -batch % block_tiles
-    if pad:  # NaN pad rows are invalid everywhere -> all-zero streams
-        x_int = jnp.concatenate(
-            [x_int, jnp.full((pad, elems), jnp.nan, x_int.dtype)])
-        eps = jnp.concatenate([eps, jnp.ones((pad,), eps.dtype)])
+    w = bins_store.itemsize * 8
     cpt = -(-elems // chunk_len)
-    wdt = _word_dtype(bins_store)
+    x = jnp.pad(x_int, ((0, 0), (0, cpt * chunk_len - elems)),
+                constant_values=jnp.nan)
+    m = planes.to_planes(x, chunk_len, w)
+    guess = eps.value.astype(jnp.float32)
+    per_chunk = [jnp.repeat(a, cpt) for a in (guess,) + tuple(eps[1:])]
 
-    def kernel(eps_ref, x_ref, bm_ref, pk_ref, cnt_ref):
-        x = x_ref[...]
-        valid = jnp.isfinite(x)
-        x0 = jnp.where(valid, x, jnp.asarray(0, x.dtype))
-        bins = quantize_broadcast(x0, eps_ref[...][:, None], dtype)
-        bins = jnp.where(valid, bins, 0).astype(bins_store)
-        bitmap, shuffled, counts = _collapse_ints(
-            bins, block_tiles, chunk_len, "delta")
-        bm_ref[...] = bitmap
-        pk_ref[...] = shuffled
-        cnt_ref[...] = counts
+    def body(xm, *eps_leaves):
+        valid = jnp.isfinite(xm)
+        x0 = jnp.where(valid, xm, planes.const(0, xm.dtype))
+        bins = quantize_broadcast(x0, Eps(*eps_leaves), dtype)
+        bins = planes.sign_extend(
+            jnp.where(valid, bins, planes.const(0, bins.dtype)), w)
+        return _forward(bins, w, "delta")
 
-    specs = [
-        pl.BlockSpec((block_tiles,), lambda i: (i,),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((block_tiles, elems), lambda i: (i, 0)),
-    ]
-    return _encode_call(kernel, (eps, x_int), specs, batch, pad,
-                        block_tiles, cpt, chunk_len, wdt, interpret)
+    out = planes.plane_call(body, m, per_chunk, planes.container(w),
+                            interpret, _step(block_tiles, cpt))
+    return _streams(out, batch, cpt, _word_dtype(bins_store))

@@ -39,6 +39,8 @@ from jax.experimental import pallas as pl
 
 from repro.core import topology
 
+from . import planes
+
 BAND = 8  # X-rows per band; (BAND+2, Y, Z) int32 x 4 arrays must fit VMEM
 
 _OFFS3 = topology.offsets(3)
@@ -96,33 +98,51 @@ def _sweep_kernel(prev_ref, cur_ref, nxt_ref, flags_ref, out_ref, changed_ref):
 
 
 # ------------------------------------------------- batched (B, tile) form
+#
+# Layout: a haloed tile (t0+2, t1+2, t2+2) sits in VMEM as
+# (t0+2, S, L) with its Y extent zero-padded up to a sublane multiple S
+# and its Z extent up to a lane multiple L, and the flags ride the same
+# grid (zero outside the tile interior).  Y/Z neighbor reads are then
+# native vreg rotations (``pltpu.roll``) instead of unaligned slices: a
+# rotation wraps only into halo or pad cells, whose flags are 0, so the
+# relax there is max(cur, 0) = cur (states are non-negative) and the
+# wrapped values are never consumed.  X neighbors are static slices of
+# the untiled leading axis.
 
-def _shift3(arr, ox: int, oy: int, oz: int):
-    """Interior-shifted static slice of a fully-resident haloed tile."""
-    x, y, z = arr.shape
-    return arr[1 + ox : x - 1 + ox, 1 + oy : y - 1 + oy, 1 + oz : z - 1 + oz]
+SUBLANE = 8
+LANE = 128
 
 
-def _make_tile_kernel(max_iters: int):
+def _make_tile_kernel(max_iters: int, t0: int):
     def _tile_kernel(sub_ref, flags_ref, out_ref, iters_ref):
-        sub = sub_ref[0]      # (t0+2, t1+2, t2+2), halos held fixed
-        flags = flags_ref[0]  # (t0, t1, t2)
+        sub = sub_ref[0]      # (t0+2, S, L): halos held fixed, pad zero
+        flags = flags_ref[0]  # (t0, S, L): zero outside the interior
+        # numpy scalars of the state's own dtype: a weak Python int would
+        # become a 64-bit literal under x64, which Mosaic cannot lower.
+        # The relax neutral is the dtype minimum: equal to max(cur, 0)
+        # for the non-negative signed lane, and the bottom of the biased
+        # lane the wrapper maps unsigned states into.
+        floor = sub.dtype.type(jnp.iinfo(sub.dtype).min)
+        ties = [sub.dtype.type(int(t)) for t in _TIES3]
 
-        def relax(cur):
-            full = sub.at[1:-1, 1:-1, 1:-1].set(cur)
-            new = cur
+        def relax(rows):
+            """One sweep over the interior rows (t0, S, L)."""
+            full = jnp.concatenate([sub[:1], rows, sub[-1:]], axis=0)
+            new = rows
             for k, (ox, oy, oz) in enumerate(_OFFS3):
-                nsub = _shift3(full, int(ox), int(oy), int(oz))
-                need = ((flags >> np.uint32(k)) & np.uint32(1)).astype(jnp.bool_)
-                # python-int tie keeps the candidate in sub's own dtype
-                # (int32 staged lane, uint64 adaptive ordered-space lane)
-                cand = nsub + int(_TIES3[k])
-                new = jnp.maximum(new, jnp.where(need, cand, 0))
+                nsub = full[1 + int(ox): 1 + int(ox) + t0]
+                nsub = planes.roll(nsub, -int(oy), 1)
+                nsub = planes.roll(nsub, -int(oz), 2)
+                need = ((flags >> np.uint32(k)) & np.uint32(1)) != 0
+                new = jnp.maximum(new, jnp.where(need, nsub + ties[k], floor))
             return new
 
-        int0 = sub[1:-1, 1:-1, 1:-1]
+        def moved(a, b):
+            return jnp.max((a != b).astype(jnp.int32)) > 0
+
+        int0 = sub[1:1 + t0]
         first = relax(int0)
-        ch1 = jnp.any(first != int0)
+        ch1 = moved(first, int0)
 
         def cond(c):
             return c[1] & (c[2] < max_iters)
@@ -130,7 +150,7 @@ def _make_tile_kernel(max_iters: int):
         def body(c):
             cur, _, it, last = c
             new = relax(cur)
-            ch = jnp.any(new != cur)
+            ch = moved(new, cur)
             it = it + 1
             return new, ch, it, jnp.where(ch, it, last)
 
@@ -139,7 +159,7 @@ def _make_tile_kernel(max_iters: int):
             (first, ch1, jnp.int32(1), jnp.where(ch1, jnp.int32(1), jnp.int32(0))),
         )
         out_ref[0] = final
-        iters_ref[0, 0] = last
+        iters_ref[...] = jnp.full(iters_ref.shape, last, jnp.int32)
 
     return _tile_kernel
 
@@ -156,30 +176,49 @@ def solve_tiles_blockwise(sub_h: jnp.ndarray, flags: jnp.ndarray,
     constraint chain — the executor's halo-exchange rounds then only pay
     for chains that genuinely cross tiles.  Returns ``(interiors
     (B, t0, t1, t2) in sub_h's dtype — int32 for the staged subbin lane,
-    uint64 for the adaptive ordered-space lane —
+    uint32/uint64 for the adaptive ordered-space lane —
     last_changed_sweep (B,) int32)`` where the
     per-tile sweep index is 0 for tiles already at their fixed point.
 
     The fixed point is schedule-independent (monotone raises, §IV-E), so
     the interiors are bit-identical to the jnp Jacobi/frontier schedules.
     """
+    return planes.per_device(
+        functools.partial(_solve_tiles, interpret=interpret))(sub_h, flags)
+
+
+def _solve_tiles(sub_h, flags, interpret: bool):
+    state_dtype = sub_h.dtype
+    biased = state_dtype == jnp.uint32
+    if biased:
+        # Mosaic has no unsigned max: flip the top bit, an order-preserving
+        # map onto int32 that commutes with the +tie wrap-around adds
+        sub_h = jax.lax.bitcast_convert_type(sub_h ^ np.uint32(1 << 31),
+                                             jnp.int32)
     b = sub_h.shape[0]
     h0, h1, h2 = sub_h.shape[1:]
     t0, t1, t2 = h0 - 2, h1 - 2, h2 - 2
+    s = -(-h1 // SUBLANE) * SUBLANE
+    lanes = -(-h2 // LANE) * LANE
+    sub_p = jnp.pad(sub_h, ((0, 0), (0, 0), (0, s - h1), (0, lanes - h2)))
+    flags_p = jnp.pad(flags, ((0, 0), (0, 0), (1, s - h1 + 1),
+                              (1, lanes - h2 + 1)))
     max_iters = t0 * t1 * t2 + 2
-    blk = lambda shape: pl.BlockSpec(shape, lambda i: (i,) + (0,) * (len(shape) - 1))  # noqa: E731
     out, iters = pl.pallas_call(
-        _make_tile_kernel(max_iters),
+        _make_tile_kernel(max_iters, t0),
         grid=(b,),
-        in_specs=[blk((1, h0, h1, h2)), blk((1, t0, t1, t2))],
-        out_specs=[blk((1, t0, t1, t2)), blk((1, 1))],
+        in_specs=[planes.chunk_block((1, h0, s, lanes)), planes.chunk_block((1, t0, s, lanes))],
+        out_specs=[planes.chunk_block((1, t0, s, lanes)), planes.chunk_block((1, 1, LANE))],
         out_shape=[
-            jax.ShapeDtypeStruct((b, t0, t1, t2), sub_h.dtype),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, t0, s, lanes), sub_h.dtype),
+            jax.ShapeDtypeStruct((b, 1, LANE), jnp.int32),
         ],
         interpret=interpret,
-    )(sub_h, flags)
-    return out, iters[:, 0]
+    )(sub_p, flags_p)
+    out = out[:, :, 1:1 + t1, 1:1 + t2]
+    if biased:
+        out = jax.lax.bitcast_convert_type(out, jnp.uint32) ^ np.uint32(1 << 31)
+    return out, iters[:, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -31,7 +31,8 @@ under a real service, behind a TCP socket) and one router: client
 writes scatter to their owning shards, concurrent region reads gather
 back byte-identical to a single-process store, and the demo SIGKILLs a
 worker mid-serving to show replica failover and the per-shard health
-report (see docs/cluster.md):
+report (see docs/cluster.md).  On a TPU the workers run in-process
+instead (``LocalCluster``): the chip belongs to one process:
 
   PYTHONPATH=src python -m repro.launch.serve --cluster 4 \
       --clients 4 --requests-per-client 3 --eb 1e-2 --tile 16,16,64
@@ -50,6 +51,7 @@ from __future__ import annotations
 import argparse
 import time
 from concurrent.futures import ThreadPoolExecutor
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -394,22 +396,24 @@ def serve_store(args):
 
 
 def serve_cluster(args):
-    """Drive a sharded store cluster: N worker subprocesses, one router.
+    """Drive a sharded store cluster: N shard workers, one router.
 
-    Spawns ``--cluster N`` shard workers (``python -m
+    Off the chip, spawns ``--cluster N`` shard workers (``python -m
     repro.cluster.worker``, each a real ``LopcStore`` directory under a
-    real ``CompressionService``, behind a TCP socket), scatters client
-    writes through the router, hammers concurrent region reads, then
-    SIGKILLs one worker mid-serving and keeps reading — the replica
-    serves the dead shard's tiles and every read stays byte-identical
-    to a single-process store.  Ends with the ``ClusterMetrics`` report:
-    per-shard health, replica-served tiles, and the workers' aggregated
-    service metrics.
+    real ``CompressionService``, behind a TCP socket); on a TPU, whose
+    chip belongs to one process, the same router drives N in-process
+    workers (``LocalCluster``).  Scatters client writes through the
+    router, hammers concurrent region reads, then kills one worker
+    mid-serving and keeps reading — the replica serves the dead shard's
+    tiles and every read stays byte-identical to a single-process
+    store.  Ends with the ``ClusterMetrics`` report: per-shard health,
+    replica-served tiles, and the workers' aggregated service metrics.
     """
     import shutil
     import tempfile
 
-    from repro.cluster import ProcessCluster
+    from repro.cluster import LocalCluster, ProcessCluster
+    from repro.cluster import router as cluster_router
     from repro.data.fields import make_scientific_field
     from repro.engine.plan import CompressionPlan
     from repro.store import LopcStore
@@ -431,11 +435,16 @@ def serve_cluster(args):
         if args.flight_dir:
             child_env["LOPC_FLIGHT_DIR"] = args.flight_dir
 
+    in_process = cluster_router.one_process_per_chip()
+    kind = "in-process workers" if in_process else "subprocesses"
+    if in_process:
+        make = LocalCluster
+    else:
+        make = partial(ProcessCluster, env=child_env)
     try:
-        with ProcessCluster(root + "/shards", n_shards, plan=plan,
-                            n_replicas=min(2, n_shards),
-                            adaptive_eb=args.adaptive_eb,
-                            env=child_env) as cluster:
+        with make(root + "/shards", n_shards, plan=plan,
+                  n_replicas=min(2, n_shards),
+                  adaptive_eb=args.adaptive_eb) as cluster:
             router = cluster.router
             fields = {}
             t0 = time.perf_counter()
@@ -477,13 +486,13 @@ def serve_cluster(args):
         mb = (sum(x.nbytes for x in fields.values())
               * args.clients * args.requests_per_client
               / len(names) / 1e6)
-        print(f"cluster: {n_shards} shard workers (subprocesses), "
+        print(f"cluster: {n_shards} shard workers ({kind}), "
               f"replication x{min(2, n_shards)}, {args.clients} clients")
         print(f"  writes     {len(names)} arrays scattered in "
               f"{t_write:.2f}s")
         print(f"  reads      {args.clients * args.requests_per_client} "
               f"region reads, byte-identical to a single-process store: "
-              f"healthy {t_healthy:.2f}s, after SIGKILL of shard "
+              f"healthy {t_healthy:.2f}s, after killing shard "
               f"{victim} {t_degraded:.2f}s (~{mb:.1f} MB served)")
         for line in router.metrics.lines(snap["workers"]):
             print(f"  {line}")
@@ -537,6 +546,7 @@ def serve_llm(args):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
     from repro.models.registry import ARCHITECTURES
 
     ap = argparse.ArgumentParser()
@@ -625,6 +635,7 @@ def main():
                          "decode needs no flag)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     _obs_configure(args)
     if args.cluster:
         serve_cluster(args)

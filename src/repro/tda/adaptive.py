@@ -53,7 +53,12 @@ import jax
 import numpy as np
 
 from ..core.bitstream import EB_LADDER_K_MAX
-from ..core.quantize import decode_base, effective_eps, quantize_broadcast
+from ..core.quantize import (
+    decode_base,
+    effective_eps,
+    eps_operand,
+    quantize_broadcast,
+)
 from ..core.topology import offsets
 from .critpoints import CLASS_REGULAR, classify_critical_points
 
@@ -208,7 +213,7 @@ def tighten_ladder(x: np.ndarray, layout, ladder: np.ndarray,
             break
         eps_tiles = eps_tight * np.exp2(k_max - ladder.astype(np.float64))
         eps_cell = eps_tiles[tid]
-        base = np.asarray(_anchor_impl(x3, eps_cell, x3.dtype))
+        base = np.asarray(_anchor_impl(x3, eps_operand(eps_cell), x3.dtype))
         tighten = _boundary_violation_tiles(base, x3, eps_cell, tid)
         tighten &= ladder < k_max
         if not tighten.any():

@@ -116,15 +116,23 @@ def critical_point_errors(original: np.ndarray, reconstructed: np.ndarray):
     return fp, fn, ft
 
 
+@jax.jit
+def order_violation_counts(original, reconstructed) -> jnp.ndarray:
+    """Per vertex, the neighbor pairs it owns whose SoS order differs.
+
+    Each undirected pair is owned by one endpoint (the positive
+    offsets), so the counts of any set of vertices add up without double
+    counting — which lets a large field be checked slab by slab.
+    """
+    lower_o, _, valid = _neighbor_relation(original)
+    lower_r, _, _ = _neighbor_relation(reconstructed)
+    half = len(topology.offsets(original.ndim)) // 2
+    viol = (lower_o != lower_r) & valid
+    return jnp.sum(viol[:half], axis=0, dtype=jnp.int32)
+
+
 def local_order_violations(original: np.ndarray, reconstructed: np.ndarray) -> int:
     """#neighbor pairs whose SoS order differs (0 for LOPC, by theorem)."""
-    o = jnp.asarray(original)
-    r = jnp.asarray(reconstructed)
-    lower_o, _, valid = _neighbor_relation(o)
-    lower_r, _, _ = _neighbor_relation(r)
-    ndim = o.ndim
-    offs = topology.offsets(ndim)
-    # only count each undirected pair once (positive offsets)
-    half = len(offs) // 2
-    viol = (lower_o != lower_r) & valid
-    return int(jnp.sum(viol[:half]))
+    counts = order_violation_counts(jnp.asarray(original),
+                                    jnp.asarray(reconstructed))
+    return int(jnp.sum(counts))

@@ -49,12 +49,13 @@ from ..core.lopc import decode_nonfinite, encode_nonfinite
 from ..core.quantize import (
     abs_bound_from_mode,
     bin_dtype_for,
+    check_backend,
+    check_eps,
     effective_eps,
 )
 from ..engine import device, halo
 from ..engine.engine import (
     DEFAULT_PLAN,
-    _check_eps,
     _serialize_tile_sections,
     _store_bin_dtype,
     _validate,
@@ -69,6 +70,7 @@ from ..engine.executor import (
     chunks_per_tile,
     fetch_compacted_streams,
     resident_capacity,
+    stream_encoder,
     use_fused_encode,
 )
 from ..engine.plan import (
@@ -153,7 +155,7 @@ class _Chain:
         self.eps_abs = min(abs_bound_from_mode(f, eb, mode)
                            for f in self.filled)
         for f in self.filled:
-            _check_eps(f, self.eps_abs)
+            check_eps(f, self.eps_abs)
         self.layout: TileLayout = plan.layout_for(shape)
         self.adaptive = adaptive_eb == "tda"
         self.ladder = None
@@ -342,13 +344,13 @@ def _compress_chain_step(members, t, kind, store, dtype, preserve_order,
     solver_c, interpret = device.resolve_solver(solver)
     fused = use_fused_encode(encode_path, capacity * layout0.tile_elems,
                              interpret)
-    encode = device.encode_tiles_fused if fused else device.encode_tiles
+    encode = stream_encoder(encode_path, fused, interpret)
     TRANSFER_COUNTS.add("h2d_tiles")
     TRANSFER_COUNTS.add("bytes_h2d", x_tiles.nbytes)
     x_dev = put(x_tiles)
     TRANSFER_COUNTS.add("h2d_aux")
     TRANSFER_COUNTS.add("bytes_h2d", eps_tiles.nbytes)
-    eps_dev = put(eps_tiles)
+    eps_dev = device.put_eps(put, eps_tiles)
 
     if adaptive and preserve_order:
         bins_enc, u_init, flags = device.resident_frontend_adaptive(
@@ -563,7 +565,7 @@ def encode_appended_frame(
     adaptive = ladder is not None
     eps_tight_abs = eps_abs * (
         2.0**-bitstream.EB_LADDER_K_MAX if adaptive else 1.0)
-    _check_eps(x, eps_tight_abs)
+    check_eps(x, eps_tight_abs)
     eps_eff = effective_eps(eps_abs)
     eps_tight = eps_eff * (
         2.0**-bitstream.EB_LADDER_K_MAX if adaptive else 1.0)
@@ -619,6 +621,7 @@ class ChainDecoder:
         self.order = bool(c.header.flags & FLAG_ORDER_PRESERVING)
         self.eps_eff = effective_eps(c.header.eps_abs)
         self.dtype = np.dtype(c.header.dtype)
+        check_backend(self.dtype, "decompress")
         self.bdt = jnp.dtype(bin_dtype_for(self.dtype))
         self.capacity = resident_capacity(
             self.layout.n_tiles, max(CAPACITY_FLOOR, plan.batch_tiles)
@@ -693,7 +696,8 @@ class ChainDecoder:
         else:
             subs = jnp.zeros_like(self.bins)
         out = device.dequantize_tiles(
-            self.bins, subs, jnp.asarray(eps), jnp.dtype(self.dtype)
+            self.bins, subs, device.put_eps(jnp.asarray, eps),
+            jnp.dtype(self.dtype)
         )
         TRANSFER_COUNTS.add("d2h_values")
         out_h = np.asarray(out)

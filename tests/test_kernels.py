@@ -219,21 +219,35 @@ def test_fused_encode_values_handles_dead_tiles(rng):
     x = (rng.standard_normal((batch, elems)) * 3).astype(np.float32)
     x[1] = np.nan          # fully dead tile (capacity pad)
     x[3, 600:] = np.nan    # in-tile pad cells
-    eps = np.full(batch, 1e-3, np.float64)
+    from repro.core.quantize import eps_operand, quantize_broadcast
+
+    eps = eps_operand(np.full(batch, 1e-3, np.float64))
     got = fused_encode.encode_values_fused(
-        jnp.asarray(x), jnp.asarray(eps), 4096, jnp.float32, jnp.int32,
+        jnp.asarray(x), eps, 8192, jnp.float32, jnp.int16,
         interpret=True)
-    # the staged equivalent: quantize valid cells, zero the rest, encode
-    from repro.core.quantize import quantize_broadcast
+    # the staged equivalent: quantize valid cells (f64 first guess),
+    # zero the rest, encode
     valid = np.isfinite(x)
     bins = np.asarray(quantize_broadcast(
-        jnp.asarray(np.where(valid, x, 0)), jnp.asarray(eps)[:, None],
-        jnp.float32))
-    bins = np.where(valid, bins, 0).astype(np.int32)
-    want = device.encode_tiles(jnp.asarray(bins), 4096, "delta")
+        jnp.asarray(np.where(valid, x, 0)), eps.expand(1), jnp.float32))
+    bins = np.where(valid, bins, 0).astype(np.int16)
+    want = device.encode_tiles(jnp.asarray(bins), 8192, "delta")
     for g, w in zip(got, want):
         assert np.array_equal(np.asarray(g), np.asarray(w))
     assert np.asarray(got[2])[1] == 0  # dead tile -> zero-count chunk
+
+
+def test_fused_encode_values_refuses_wide_bins():
+    """The kernel's f32 first guess is exact only for 16-bit bins; wider
+    streams stay on the staged quantize."""
+    from repro.core.quantize import eps_operand
+    from repro.kernels import fused_encode
+
+    eps = eps_operand(np.full(2, 1e-3, np.float64))
+    with pytest.raises(ValueError, match="16-bit bins"):
+        fused_encode.encode_values_fused(
+            jnp.zeros((2, 64), jnp.float32), eps, 4096, jnp.float32,
+            jnp.int32, interpret=True)
 
 
 def test_fused_encode_matches_staged_on_determinism_cases():
