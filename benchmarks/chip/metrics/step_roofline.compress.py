@@ -1,0 +1,3 @@
+"""Percent of the HBM roofline of the whole compress step: input and
+container bytes over 819 GB/s, against the device busy time."""
+from benchmarks.chip.readers import step_roofline as read  # noqa: F401
