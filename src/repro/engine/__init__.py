@@ -21,6 +21,8 @@ Probes: ``device.TRACE_COUNTS`` / ``device.trace_count()`` expose the
 jit-trace counter used to assert shape stability;
 ``executor.TRANSFER_COUNTS`` / ``executor.transfer_count()`` count
 host↔device crossings (one upload + one download per compress group).
+Both are views over registry counter families (``lopc_traces_total``,
+``lopc_transfers_total``; see :mod:`repro.obs`).
 """
 from .engine import (
     ADAPTIVE_EB_MODES,

@@ -16,13 +16,11 @@ device batch, and chunk boundaries never cross a request (compress) or a
 tile (decode), so bucketing never changes a request's container bytes —
 the same invariant the PR-3 width/group-key machinery already tests.
 
-``BUCKET_COUNTS`` records every device batch by ``(kind, capacity)`` and
-``PAD_COUNTS`` the real/padded tile split, so benches and the service
-metrics can report bucket occupancy and pad waste per load point.
+Bucket occupancy and pad waste are reported by the service metrics
+(the ``lopc_service_bucket_*`` and ``lopc_service_device_group_total``
+registry families), fed from the engine's ``group_cb`` batch plans.
 """
 from __future__ import annotations
-
-from collections import Counter
 
 CAPACITY_FLOOR = 8
 
@@ -31,9 +29,6 @@ CAPACITY_FLOOR = 8
 # traffic whose single requests fit (an oversized single request gets a
 # chunk of its own at the smallest class that holds it).
 MAX_DOUBLINGS = 4
-
-BUCKET_COUNTS: Counter = Counter()  # (kind, capacity) -> batches
-PAD_COUNTS: Counter = Counter()     # "real" / "padded" tile tallies
 
 
 def bucket_capacity(n_tiles: int, floor: int = CAPACITY_FLOOR) -> int:
@@ -92,19 +87,3 @@ def plan_tile_chunks(n_tiles: int, floor: int = CAPACITY_FLOOR):
     base, extra = divmod(n_tiles, q)
     return [base + (1 if i < extra else 0) for i in range(q)]
 
-
-def record_batch(kind: str, n_real: int, capacity: int) -> None:
-    BUCKET_COUNTS[(kind, capacity)] += 1
-    PAD_COUNTS["real"] += n_real
-    PAD_COUNTS["padded"] += capacity - n_real
-
-
-def reset_bucket_counts() -> None:
-    BUCKET_COUNTS.clear()
-    PAD_COUNTS.clear()
-
-
-def pad_waste() -> float:
-    """Padded tiles per real tile since the last reset (0.0 when idle)."""
-    real = PAD_COUNTS["real"]
-    return PAD_COUNTS["padded"] / real if real else 0.0

@@ -35,7 +35,6 @@ batch — the core of ``compress_many``'s request coalescing.
 """
 from __future__ import annotations
 
-from collections import Counter
 from functools import partial
 
 import jax
@@ -48,11 +47,16 @@ from ..codecs.transforms import delta_decode, delta_encode, zigzag_decode, zigza
 from ..core import topology
 from ..core.floatbits import int_dtype_for, ordered_to_float
 from ..core.quantize import Eps, decode_base_ordered, eps_operand, quantize_broadcast
+from ..obs import CounterView, REGISTRY
 
 # Incremented inside traced function bodies: Python side effects run only
 # while tracing, so this counts jit traces, not executions.  Tests use it
-# to assert shape stability across many field shapes.
-TRACE_COUNTS: Counter = Counter()
+# to assert shape stability across many field shapes.  A view over the
+# registry's ``lopc_traces_total`` family (label ``program``), so the
+# metrics exposition shows it too.
+TRACE_COUNTS: CounterView = CounterView(REGISTRY.counter(
+    "lopc_traces_total", "jit traces of the engine's device programs"),
+    label="program")
 
 SOLVERS = ("auto", "jacobi", "frontier", "blockwise")
 
@@ -320,7 +324,7 @@ def _resident_quantize(x_h, eps, dtype, preserve_order: bool):
     """Quantize one resident tile batch; NaN in x_h marks cells outside
     the field (tile pad, halo border, pad tiles), so validity travels
     *inside* the one tile upload instead of as a second array."""
-    TRACE_COUNTS["resident_quantize"] += 1
+    TRACE_COUNTS.add("resident_quantize")
     valid_h = jnp.isfinite(x_h)
     x0 = jnp.where(valid_h, x_h, jnp.asarray(0, x_h.dtype))
     bins_h = quantize_broadcast(x0, eps.expand(3), dtype)
@@ -344,7 +348,7 @@ def _resident_flags(bins_m, vals_m):
     the quantize chain into every one of the 14 offset terms (~10x
     slower on CPU, and optimization_barrier does not stop it).
     """
-    TRACE_COUNTS["resident_flags"] += 1
+    TRACE_COUNTS.add("resident_flags")
     return topology.order_flags(bins_m, vals_m)
 
 
@@ -394,7 +398,7 @@ def _bias_ordered(o: jnp.ndarray) -> jnp.ndarray:
 def _resident_quantize_adaptive(x_h, eps, dtype):
     """Adaptive frontend: quantize at per-tile eps and seed the
     ordered-space solve state at each cell's decoded bin base."""
-    TRACE_COUNTS["resident_quantize_adaptive"] += 1
+    TRACE_COUNTS.add("resident_quantize_adaptive")
     valid_h = jnp.isfinite(x_h)
     x0 = jnp.where(valid_h, x_h, jnp.asarray(0, x_h.dtype))
     eps_b = eps.expand(3)
@@ -412,7 +416,7 @@ def _resident_quantize_adaptive(x_h, eps, dtype):
 def _resident_flags_adaptive(vals_m):
     """All-pairs order flags on the merged layout (see _resident_flags
     for why this is a separate jit from quantize)."""
-    TRACE_COUNTS["resident_flags_adaptive"] += 1
+    TRACE_COUNTS.add("resident_flags_adaptive")
     return topology.order_flags_all(vals_m)
 
 
@@ -431,7 +435,7 @@ def resident_frontend_adaptive(x_h, eps, dtype):
 def _ordered_delta(u_final, u_init):
     """Stored adaptive subbin: ordered distance climbed above the bin
     base (non-negative; 0 at invalid cells, whose state never moves)."""
-    TRACE_COUNTS["ordered_delta"] += 1
+    TRACE_COUNTS.add("ordered_delta")
     st = jnp.dtype(jnp.dtype(u_final.dtype).str.replace("u", "i"))
     return (u_final - u_init).astype(st)
 
@@ -441,7 +445,7 @@ def resident_solve(flags, idx, mask, max_rounds, solver: str,
                    interpret: bool, local_max_iters: int, sub0=None):
     """Jitted wrapper of the halo-round solve (see _resident_solve).
     ``max_rounds`` is traced, so it never forces a retrace."""
-    TRACE_COUNTS["resident_solve"] += 1
+    TRACE_COUNTS.add("resident_solve")
     return _resident_solve(flags, _merge(idx), _merge(mask), solver,
                            interpret, local_max_iters, max_rounds, sub0)
 
@@ -449,14 +453,14 @@ def resident_solve(flags, idx, mask, max_rounds, solver: str,
 @partial(jax.jit, static_argnames=("chunk_len", "transform"))
 def encode_tiles(ints, chunk_len: int, transform: str):
     """Jitted lossless stage over (C, tile_elems) resident integers."""
-    TRACE_COUNTS["encode"] += 1
+    TRACE_COUNTS.add("encode")
     return _encode_ints(ints, chunk_len, transform)
 
 
 @partial(jax.jit, static_argnames=("chunk_len", "transform", "interpret"))
 def _fused_encode_ints_program(ints, chunk_len: int, transform: str,
                                interpret: bool):
-    TRACE_COUNTS["fused_encode"] += 1
+    TRACE_COUNTS.add("fused_encode")
     from ..kernels.fused_encode import encode_ints_fused
 
     return encode_ints_fused(ints, chunk_len, transform,
@@ -477,7 +481,7 @@ def encode_tiles_fused(ints, chunk_len: int, transform: str):
          static_argnames=("dtype", "bins_store", "bins_chunk", "interpret"))
 def _fused_encode_values_program(x_h, eps, dtype, bins_store,
                                  bins_chunk: int, interpret: bool):
-    TRACE_COUNTS["fused_encode_values"] += 1
+    TRACE_COUNTS.add("fused_encode_values")
     from ..kernels.fused_encode import encode_values_fused
 
     capacity = x_h.shape[0]
@@ -523,7 +527,7 @@ def compact_streams(bitmap, words):
     popcount exactly (``rze_bitmap`` construction), which the host
     recomputes from the restored bitmap.
     """
-    TRACE_COUNTS["compact"] += 1
+    TRACE_COUNTS.add("compact")
 
     def front_pack(flat, live):
         cum = jnp.cumsum(live, dtype=jnp.int32)
@@ -611,14 +615,14 @@ def _sub_max(sub):
     back mid-pipeline, to pick the narrowest subbin section width (the
     solve must finish before the sub encode anyway, so this readback
     rides the natural synchronization point)."""
-    TRACE_COUNTS["sub_max"] += 1
+    TRACE_COUNTS.add("sub_max")
     return jnp.max(sub)
 
 
 @partial(jax.jit, static_argnames=("tile_elems", "transform", "out_dtype"))
 def decode_tiles(bitmap, packed, tile_elems: int, transform: str, out_dtype):
     """Jitted inverse of encode_tiles -> (C, tile_elems) resident ints."""
-    TRACE_COUNTS["decode"] += 1
+    TRACE_COUNTS.add("decode")
     return _decode_ints(bitmap, packed, tile_elems, transform, out_dtype)
 
 
@@ -635,14 +639,14 @@ def residual_tiles(bins_enc, prev_bins):
     """Temporal bin residual of one resident frame batch vs the decoded
     previous-frame bins (identical integers, since the bins stream is
     lossless)."""
-    TRACE_COUNTS["residual"] += 1
+    TRACE_COUNTS.add("residual")
     return bins_enc - prev_bins
 
 
 @jax.jit
 def accumulate_bins(prev_bins, residual):
     """Decode-side inverse of :func:`residual_tiles`."""
-    TRACE_COUNTS["accumulate"] += 1
+    TRACE_COUNTS.add("accumulate")
     return prev_bins + residual.astype(prev_bins.dtype)
 
 
@@ -650,7 +654,7 @@ def accumulate_bins(prev_bins, residual):
 def dequantize_tiles(bins, subbins, eps, dtype):
     """(C, E) resident bins+subbins -> reconstructed values, per-tile
     eps (mirroring the compress side's per-tile bounds)."""
-    TRACE_COUNTS["dequantize"] += 1
+    TRACE_COUNTS.add("dequantize")
     base = decode_base_ordered(bins, eps.expand(1), dtype)
     idt = int_dtype_for(dtype)
     # an adaptive f32 subbin stream can be wider than f32's ordered-int
@@ -689,7 +693,7 @@ def resident_decode_plain(bitmap, packed, eps, tile_elems: int, dtype):
 @partial(jax.jit, static_argnames=("tile_elems", "dtype", "interpret"))
 def _fused_decode_program(bitmap, packed, sub_bitmap, sub_packed, eps,
                           tile_elems: int, dtype, interpret: bool):
-    TRACE_COUNTS["fused_decode"] += 1
+    TRACE_COUNTS.add("fused_decode")
     from ..kernels.fused_decode import decode_tiles_fused
 
     return decode_tiles_fused(bitmap, packed, sub_bitmap, sub_packed, eps,

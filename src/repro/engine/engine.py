@@ -262,8 +262,9 @@ def compress_many(
     ebs = list(eb) if np.ndim(eb) else [eb] * len(fields)
     if len(ebs) != len(fields):
         raise ValueError("eb must be a scalar or one bound per field")
-    reqs = [_Request(x, e, mode, plan, adaptive_eb)
-            for x, e in zip(fields, ebs)]
+    with obs.span("engine.admit", n_requests=len(fields)):
+        reqs = [_Request(x, e, mode, plan, adaptive_eb)
+                for x, e in zip(fields, ebs)]
     ex = (Executor(plan, solver, put, encode_path=encode_path) if put
           else default_executor(plan, solver, encode_path=encode_path))
 
@@ -286,11 +287,16 @@ def compress_many(
             })
         with obs.span("engine.compress_group", dtype=str(dtype),
                       tile=list(tile), n_requests=len(members),
-                      n_tiles=sum(reqs[i].layout.n_tiles for i in members)):
-            _compress_group(
+                      n_tiles=sum(reqs[i].layout.n_tiles for i in members)
+                      ) as group_span:
+            gs = _compress_group(
                 [reqs[i] for i in members], dtype, ex, preserve_order,
                 [blobs, stats], members, return_stats,
             )
+            if preserve_order and obs.enabled():
+                group_span.set_tag("halo_rounds", gs.halo_rounds())
+                group_span.set_tag("local_sweeps",
+                                   int(gs.local_sweeps.max(initial=0)))
     if return_stats:
         return blobs, stats
     return blobs
@@ -300,26 +306,30 @@ def _compress_group(reqs, dtype, ex: Executor, preserve_order, out, members,
                     return_stats):
     """Plan-side assembly for one (dtype, tile_shape) group: build the
     NaN-marked haloed tile batch, run the executor, serialize per-tile
-    sections into one v2 container per request."""
+    sections into one v2 container per request.  Returns the group's
+    :class:`~.executor.GroupStreams` (its solver diagnostics)."""
     blobs, stats = out
     nan = np.asarray(np.nan, dtype)
 
     # ---- plan: tiles of every request, concatenated (shared batches).
     # NaN marks every cell outside a field (in-tile pad, halo border), so
     # validity rides inside the single tile upload.
-    x_tiles, eps_tiles, ranges = [], [], []
-    n_total = 0
-    for r in reqs:
-        arr3 = r.x.reshape(r.layout.canonical)
-        x_pb = padded_with_border(arr3, r.layout, nan)
-        x_tiles.append(extract_halo_tiles(x_pb, r.layout))
-        eps_tiles.append(r.eps_tiles())
-        ranges.append((n_total, n_total + r.layout.n_tiles))
-        n_total += r.layout.n_tiles
+    with obs.span("engine.tile"):
+        x_tiles, eps_tiles, ranges = [], [], []
+        n_total = 0
+        for r in reqs:
+            arr3 = r.x.reshape(r.layout.canonical)
+            x_pb = padded_with_border(arr3, r.layout, nan)
+            x_tiles.append(extract_halo_tiles(x_pb, r.layout))
+            eps_tiles.append(r.eps_tiles())
+            ranges.append((n_total, n_total + r.layout.n_tiles))
+            n_total += r.layout.n_tiles
+        x_tiles = np.concatenate(x_tiles)
+        eps_tiles = np.concatenate(eps_tiles)
 
     # ---- execute: the whole pipeline, device-resident
     gs = ex.compress_tiles(
-        np.concatenate(x_tiles), np.concatenate(eps_tiles),
+        x_tiles, eps_tiles,
         tuple(r.layout for r in reqs), dtype, preserve_order,
         bins_store=reqs[0].bins_store,  # identical across the group (key)
         adaptive=reqs[0].adaptive,      # ditto — part of the group key
@@ -333,44 +343,48 @@ def _compress_group(reqs, dtype, ex: Executor, preserve_order, out, members,
             r.sweeps = local + max(0, rounds - 1)
 
     # ---- per-tile serialization, then one v2 container per request
-    bins_sections = _serialize_tile_sections(gs.bins, n_total, gs.bins_cpt)
-    if preserve_order:
-        sub_sections = _serialize_tile_sections(gs.subs, n_total, gs.subs_cpt)
-    else:
-        sub_sections = [b""] * n_total
+    with obs.span("engine.serialize", n_tiles=n_total):
+        bins_sections = _serialize_tile_sections(gs.bins, n_total,
+                                                 gs.bins_cpt)
+        if preserve_order:
+            sub_sections = _serialize_tile_sections(gs.subs, n_total,
+                                                    gs.subs_cpt)
+        else:
+            sub_sections = [b""] * n_total
 
-    for r, (lo, hi), i in zip(reqs, ranges, members):
-        flags = FLAG_ORDER_PRESERVING if preserve_order else 0
-        extra = {}
-        if r.nonfinite is not None:
-            flags |= FLAG_HAS_NONFINITE
-            extra[bitstream.TAG_NONFINITE] = r.nonfinite
-        if r.adaptive:
-            flags |= FLAG_ADAPTIVE_EB
-            extra[bitstream.TAG_EB_LADDER] = \
-                bitstream.serialize_eb_ladder(r.ladder)
-        header = bitstream.Header(
-            dtype=np.dtype(dtype), shape=r.x.shape, eb_mode=r.mode,
-            eb=r.eb, eps_abs=float(r.eps_abs), flags=flags,
-        )
-        tiles = list(zip(bins_sections[lo:hi], sub_sections[lo:hi]))
-        blob = bitstream.write_container_v2(
-            header, r.layout.tile, r.layout.grid, tiles, extra
-        )
-        blobs[i] = blob
-        if return_stats:
-            bin_bytes = sum(len(b) for b, _ in tiles)
-            subbin_bytes = sum(len(s) for _, s in tiles)
-            stats[i] = CompressStats(
-                raw_bytes=r.x.nbytes,
-                total_bytes=len(blob),
-                bin_bytes=bin_bytes,
-                subbin_bytes=subbin_bytes,
-                header_bytes=len(blob) - bin_bytes - subbin_bytes,
-                n_sweeps=r.sweeps,
-                eps_abs=float(r.eps_abs),
+        for r, (lo, hi), i in zip(reqs, ranges, members):
+            flags = FLAG_ORDER_PRESERVING if preserve_order else 0
+            extra = {}
+            if r.nonfinite is not None:
+                flags |= FLAG_HAS_NONFINITE
+                extra[bitstream.TAG_NONFINITE] = r.nonfinite
+            if r.adaptive:
+                flags |= FLAG_ADAPTIVE_EB
+                extra[bitstream.TAG_EB_LADDER] = \
+                    bitstream.serialize_eb_ladder(r.ladder)
+            header = bitstream.Header(
+                dtype=np.dtype(dtype), shape=r.x.shape, eb_mode=r.mode,
+                eb=r.eb, eps_abs=float(r.eps_abs), flags=flags,
             )
+            tiles = list(zip(bins_sections[lo:hi], sub_sections[lo:hi]))
+            blob = bitstream.write_container_v2(
+                header, r.layout.tile, r.layout.grid, tiles, extra
+            )
+            blobs[i] = blob
+            if return_stats:
+                bin_bytes = sum(len(b) for b, _ in tiles)
+                subbin_bytes = sum(len(s) for _, s in tiles)
+                stats[i] = CompressStats(
+                    raw_bytes=r.x.nbytes,
+                    total_bytes=len(blob),
+                    bin_bytes=bin_bytes,
+                    subbin_bytes=subbin_bytes,
+                    header_bytes=len(blob) - bin_bytes - subbin_bytes,
+                    n_sweeps=r.sweeps,
+                    eps_abs=float(r.eps_abs),
+                )
 
+    return gs
 
 def compress(field, eb, mode="noa", preserve_order=True, solver="auto",
              plan=None, return_stats=False, put=None, encode_path="auto",
@@ -464,18 +478,19 @@ def _decode_runs(runs, plan, group_cb=None, decode_path: str = "auto"):
                 "n_tiles": n_tiles,
                 "tile_batches": _decode_batches(n_tiles, plan),
             })
-        items, spans = [], []
-        for i in members:
-            c, layout, tile_ids = runs[i]
-            eps_eff = effective_eps(c.header.eps_abs)
-            # per-tile eb-ladder scaling (all-zero for non-adaptive
-            # containers); eps is decode *data*, not program shape, so
-            # adaptive and uniform tiles share device batches here.
-            ladder = c.eb_ladder()
-            start = len(items)
-            items.extend((c, t, eps_eff * 2.0 ** -int(ladder[t]))
-                         for t in tile_ids)
-            spans.append((i, start, len(items)))
+        with obs.span("engine.parse", n_requests=len(members)):
+            items, spans = [], []
+            for i in members:
+                c, layout, tile_ids = runs[i]
+                eps_eff = effective_eps(c.header.eps_abs)
+                # per-tile eb-ladder scaling (all-zero for non-adaptive
+                # containers); eps is decode *data*, not program shape,
+                # so adaptive and uniform tiles share device batches here.
+                ladder = c.eb_ladder()
+                start = len(items)
+                items.extend((c, t, eps_eff * 2.0 ** -int(ladder[t]))
+                             for t in tile_ids)
+                spans.append((i, start, len(items)))
         with obs.span("engine.decode_group", dtype=str(dtype),
                       tile=list(tile), n_requests=len(members),
                       n_tiles=len(items)):
@@ -569,13 +584,15 @@ def decompress_many(blobs, plan: CompressionPlan | None = None,
     :func:`compress_many`'s per-device-group reporting hook."""
     plan = plan or DEFAULT_PLAN
     parsed = []
-    for b in blobs:
-        c = bitstream.read_container_v2(b)
-        layout = container_layout(c)
-        parsed.append((c, layout, list(range(layout.n_tiles))))
+    with obs.span("engine.parse"):
+        for b in blobs:
+            c = bitstream.read_container_v2(b)
+            layout = container_layout(c)
+            parsed.append((c, layout, list(range(layout.n_tiles))))
     values = _decode_runs(parsed, plan, group_cb, decode_path)
-    return [_assemble_field(v, c, layout)
-            for v, (c, layout, _) in zip(values, parsed)]
+    with obs.span("engine.assemble", n_fields=len(parsed)):
+        return [_assemble_field(v, c, layout)
+                for v, (c, layout, _) in zip(values, parsed)]
 
 
 def decompress_roi(blob: bytes, region: tuple[slice, ...],

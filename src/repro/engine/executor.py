@@ -199,7 +199,7 @@ def resident_capacity(n_tiles: int, floor: int = CAPACITY_FLOOR) -> int:
     executor's packing cap and each class is one trace of the fused
     programs, paid once (or prewarmed) and then warm for every group
     that lands in it.  Pad-tile compute waste is bounded at 2x and is
-    reported via ``buckets.pad_waste`` / the service metrics.
+    reported by the service metrics (``bucket_pad_waste``).
     """
     return buckets.bucket_capacity(n_tiles, floor)
 
@@ -217,10 +217,19 @@ class GroupStreams:
 
     bins: tuple[np.ndarray, np.ndarray, np.ndarray]   # bitmap, packed, counts
     subs: tuple[np.ndarray, np.ndarray, np.ndarray] | None
-    local_sweeps: np.ndarray                          # (capacity,) int32
-    last_round: np.ndarray                            # (capacity,) int32
+    local_sweeps: np.ndarray                          # (n_tiles,) int32
+    last_round: np.ndarray                            # (n_tiles,) int32
     bins_cpt: int
     subs_cpt: int
+    chunk_tiles: tuple[int, ...]                      # real tiles per chunk
+
+    def halo_rounds(self) -> int:
+        """Iterations the solve's halo-round loop ran, summed over the
+        group's device chunks: a chunk's loop stops after the first
+        round in which no tile moved, so it ran 1 + its last round."""
+        bounds = np.cumsum((0, *self.chunk_tiles))
+        return sum(1 + int(self.last_round[lo:hi].max(initial=0))
+                   for lo, hi in zip(bounds[:-1], bounds[1:]))
 
 
 class Executor:
@@ -307,14 +316,15 @@ class Executor:
             r0, r1 = int(offsets[lo]), int(offsets[hi])
             n_chunk = r1 - r0
             capacity = resident_capacity(n_chunk, floor)
-            idx, mask = halo.group_index(layouts[lo:hi], capacity)
-            xc, ec = x_tiles[r0:r1], eps_tiles[r0:r1]
-            pad = capacity - n_chunk
-            if pad:
-                xc = np.concatenate([
-                    xc, np.full((pad,) + xc.shape[1:], np.nan, xc.dtype),
-                ])
-                ec = np.concatenate([ec, np.ones(pad, np.float64)])
+            with span("exec.pack", tiles=n_chunk, capacity=capacity):
+                idx, mask = halo.group_index(layouts[lo:hi], capacity)
+                xc, ec = x_tiles[r0:r1], eps_tiles[r0:r1]
+                pad = capacity - n_chunk
+                if pad:
+                    xc = np.concatenate([
+                        xc, np.full((pad,) + xc.shape[1:], np.nan, xc.dtype),
+                    ])
+                    ec = np.concatenate([ec, np.ones(pad, np.float64)])
             TRANSFER_COUNTS.add("h2d_tiles")
             TRANSFER_COUNTS.add("bytes_h2d", xc.nbytes)
             TRANSFER_COUNTS.add("h2d_aux", 3)
@@ -342,7 +352,6 @@ class Executor:
                         encode_fused=fused, adaptive=adaptive,
                     )
                 fence(bins_s, sub_dev, local1, last_round, sub_max)
-            buckets.record_batch("compress", n_chunk, capacity)
             chunks.append([n_chunk, capacity, bins_s, sub_dev, local1,
                            last_round, sub_max])
 
@@ -404,7 +413,7 @@ class Executor:
                 last_round = np.concatenate(
                     [h[3][:n] for h, n in zip(host, ns)])
         return GroupStreams(bins_s, subs_s, local1, last_round, bins_cpt,
-                            subs_cpt)
+                            subs_cpt, tuple(ns))
 
     # ------------------------------------------------------------- decode
 
@@ -473,7 +482,6 @@ class Executor:
                       fused: bool) -> np.ndarray:
         n = len(items)
         DECODE_COUNTS.add("batches")
-        buckets.record_batch("decode", n, batch)
 
         def alloc(word):
             chunk_len = _CHUNK_WORDS[word]

@@ -17,9 +17,9 @@ tracing is off:
   gauges, and histograms with locked increments and a Prometheus-style
   text exposition.  The registry *backs* the existing
   ``ServiceMetrics``/``ClusterMetrics`` snapshots (same numbers, new
-  storage), and the executor's ``TRANSFER_COUNTS``/``DECODE_COUNTS``
-  module globals are now :class:`CounterView` compatibility views over
-  registry counter families.
+  storage), and the engine's ``TRANSFER_COUNTS``/``DECODE_COUNTS`` and
+  ``TRACE_COUNTS`` module globals are :class:`CounterView`
+  compatibility views over registry counter families.
 * :mod:`~repro.obs.recorder` — a bounded per-process flight recorder of
   recent span events, dumped (with the active trace's span tree) on
   request failure, poison isolation, and ``ShardDown`` failover, so an

@@ -10,9 +10,9 @@ a replacement API — and :meth:`MetricsRegistry.expose_text` renders
 everything in the Prometheus text exposition format for
 ``serve.py --metrics-port``.
 
-:class:`CounterView` keeps the executor's historical module globals
-(``TRANSFER_COUNTS``/``DECODE_COUNTS``) working: it is a
-``collections.Counter``-shaped view over one counter family whose
+:class:`CounterView` keeps the engine's historical module globals
+(``TRANSFER_COUNTS``/``DECODE_COUNTS``/``TRACE_COUNTS``) working: it is
+a ``collections.Counter``-shaped view over one counter family whose
 ``add()`` is atomic.  Existing readers (``dict(TRANSFER_COUNTS)``,
 ``TRANSFER_COUNTS["h2d_tiles"]``, ``.clear()``) behave exactly as
 before, including the Counter convention that a missing key reads 0.

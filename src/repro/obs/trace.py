@@ -21,12 +21,25 @@ Model (normative; docs/observability.md walks the full tree):
   finished spans and returns them in the reply header so the router's
   tracer holds the whole tree.
 
+While enabled, two hooks tie spans to JAX (imported lazily, as
+:func:`fence` does):
+
+* every ``with`` span also enters a ``jax.profiler.TraceAnnotation`` of
+  its name on its own thread, so the span appears on the host plane of
+  any ``jax.profiler`` capture, on the profiler's clock, beside the
+  device operations.  Detached spans (:func:`start_span`) begin and end
+  on different threads and stay off the profiler's timeline.
+* one ``jax.monitoring`` duration listener adds ``compiles`` (count) and
+  ``compile_ms`` tags to the innermost ``with`` span open on the thread
+  that compiled, so a trace shows which stage paid a recompile.
+
 Zero-cost when disabled: ``span()`` returns a shared no-op context
-manager (no allocation, no lock), :func:`fence` does nothing, and the
-flight recorder sees no events.  The only always-on cost is one
-attribute read per call site.  Set ``LOPC_TRACE=1`` in the environment
-to enable tracing at import time (how subprocess cluster workers are
-switched on by ``serve.py --trace-out``).
+manager (no allocation, no lock), :func:`fence` does nothing, no
+annotation is entered, no listener is registered, and the flight
+recorder sees no events.  The only always-on cost is one attribute read
+per call site.  Set ``LOPC_TRACE=1`` in the environment to enable
+tracing at import time (how subprocess cluster workers are switched on
+by ``serve.py --trace-out``).
 """
 from __future__ import annotations
 
@@ -132,20 +145,48 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
-class _SpanScope:
-    """``with`` wrapper: installs the span's context, finishes on exit."""
+# the ``with`` spans open on each thread, innermost last: where the
+# compile listener puts its tags
+_OPEN = threading.local()
 
-    __slots__ = ("span", "_token")
+_TRACE_ANNOTATION = None   # jax.profiler.TraceAnnotation, once imported
+
+
+def _open_spans() -> list:
+    stack = getattr(_OPEN, "spans", None)
+    if stack is None:
+        stack = _OPEN.spans = []
+    return stack
+
+
+def _annotation(name: str):
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation as _TRACE_ANNOTATION
+    return _TRACE_ANNOTATION(name)
+
+
+class _SpanScope:
+    """``with`` wrapper: installs the span's context and its profiler
+    annotation, finishes both on exit."""
+
+    __slots__ = ("span", "_token", "_ann")
 
     def __init__(self, s: Span):
         self.span = s
         self._token = None
+        self._ann = None
 
     def __enter__(self) -> Span:
         self._token = _ctx.set(self.span.context())
+        _open_spans().append(self.span)
+        self._ann = _annotation(self.span.name)
+        self._ann.__enter__()
         return self.span
 
     def __exit__(self, et, ev, tb) -> bool:
+        self._ann.__exit__(None, None, None)
+        _open_spans().pop()
         _ctx.reset(self._token)
         if et is not None:
             self.span.status = et.__name__
@@ -175,12 +216,14 @@ class Tracer:
             if max_spans is not None and max_spans != self._spans.maxlen:
                 self._spans = deque(self._spans, maxlen=max_spans)
             self.enabled = True
+            _listen_for_compiles(True)
         return self
 
     def disable(self) -> None:
         with self._lock:
             self.enabled = False
             self._spans.clear()
+            _listen_for_compiles(False)
 
     def add_listener(self, fn) -> None:
         with self._lock:
@@ -248,6 +291,39 @@ class Tracer:
             out = list(self._spans)
             self._spans.clear()
         return out
+
+
+# ------------------------------------------------------- compile listener
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_LISTENER = False   # registered with jax.monitoring
+
+
+def _on_compile(event: str, duration_secs: float, **_) -> None:
+    """Tag the innermost ``with`` span open on the compiling thread."""
+    if event != _COMPILE_EVENT:
+        return
+    stack = getattr(_OPEN, "spans", None)
+    if not stack:
+        return
+    tags = stack[-1].tags
+    tags["compiles"] = tags.get("compiles", 0) + 1
+    tags["compile_ms"] = round(tags.get("compile_ms", 0.0)
+                               + duration_secs * 1e3, 3)
+
+
+def _listen_for_compiles(on: bool) -> None:
+    """(Un)register :func:`_on_compile`; called under the tracer lock."""
+    global _COMPILE_LISTENER
+    if on == _COMPILE_LISTENER:
+        return
+    from jax import monitoring
+
+    if on:
+        monitoring.register_event_duration_secs_listener(_on_compile)
+    else:
+        monitoring.unregister_event_duration_listener(_on_compile)
+    _COMPILE_LISTENER = on
 
 
 _TRACER = Tracer()
