@@ -7,13 +7,14 @@ The engine's resident tile batches are plain leading-axis arrays, so
 sharding LOPC across devices is just placing that axis over a mesh
 axis: ``compress_fields_sharded`` routes ``engine.compress_many``
 through a ``put`` hook that lays every executor upload (tiles, eps,
-halo-index tables) out with a NamedSharding.  The same device-resident
-executor then runs unchanged: quantize/flags/solve/encode stay sharded
-over tiles, and the halo-exchange gather is a device-side collective
-over the resident batch — no host round-trips appear on the sharded
-path either.  Bytes are identical to the single-device path — the
-engine's programs are schedule-independent — which is what makes the
-sharded path safe to enable anywhere.
+the halo neighbour table) out with a NamedSharding.  The same
+device-resident executor then runs unchanged: quantize/flags/solve/
+encode stay sharded over tiles, and the halo build's face-plane gathers
+are device-side collectives over the resident batch — no host
+round-trips appear on the sharded path either.  Bytes are identical to
+the single-device path — the engine's programs are
+schedule-independent — which is what makes the sharded path safe to
+enable anywhere.
 
 Gradient compression (distributed-optimization trick, DESIGN.md §5):
 int8 quantization of the gradient stream using the same guaranteed-bound
@@ -53,7 +54,7 @@ def make_tile_put(mesh, axis: str = "data"):
     """
     n = mesh.shape[axis]
     # ``jax.make_mesh`` gives Explicit axes, whose sharding-in-types rules
-    # refuse the executor's pads and halo gather on a sharded tile axis;
+    # refuse the executor's pads and halo gathers on a sharded tile axis;
     # Auto axes leave placement to the compiler, which is all we ask.
     mesh = Mesh(mesh.devices, mesh.axis_names,
                 axis_types=(AxisType.Auto,) * len(mesh.axis_names))

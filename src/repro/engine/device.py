@@ -10,12 +10,12 @@ bit-identical to the legacy whole-field path.
 
 The centerpiece is :func:`resident_compress`: it takes the uploaded
 tile batch and runs quantize → order flags → subbin solve (tile-local
-solves + on-device halo-exchange rounds via the precomputed gather
-table from engine/halo.py) → delta/zigzag/BIT/RZE as a short chain of
-jitted stage programs whose intermediates never leave the device; the
-halo-round ``while_loop`` carries its state in place (XLA buffer reuse
-— no per-round host scatter/gather, no per-round re-upload, not even a
-per-round scalar readback).
+solves + on-device halo-exchange rounds built from the per-tile
+face-neighbour table of engine/halo.py) → delta/zigzag/BIT/RZE as a
+short chain of jitted stage programs whose intermediates never leave
+the device; the halo-round ``while_loop`` carries its state in place
+(XLA buffer reuse — no per-round host scatter/gather, no per-round
+re-upload, not even a per-round scalar readback).
 
 Solver backends (all converge to the same least fixed point, so the
 output bytes are identical — the paper's schedule independence, §IV-E):
@@ -48,6 +48,7 @@ from ..core import topology
 from ..core.floatbits import int_dtype_for, ordered_to_float
 from ..core.quantize import Eps, decode_base_ordered, eps_operand, quantize_broadcast
 from ..obs import CounterView, REGISTRY
+from . import halo
 
 # Incremented inside traced function bodies: Python side effects run only
 # while tracing, so this counts jit traces, not executions.  Tests use it
@@ -114,6 +115,43 @@ def _pad_halo(x4: jnp.ndarray, fill=0) -> jnp.ndarray:
     return jnp.pad(x4, ((0, 0),) + ((1, 1),) * 3, constant_values=fill)
 
 
+def _halo_build(cur: jnp.ndarray, nbr: jnp.ndarray) -> jnp.ndarray:
+    """(C, t0, t1, t2) interiors -> (C, t0+2, t1+2, t2+2) haloed tiles.
+
+    ``nbr`` is a (C, 7) table of ``engine.halo``: face-neighbour tile
+    ids per axis (low, high), ``-1`` where none, then the tile's own id
+    (``-1`` on pad rows, whose interiors read 0).  Three passes, axis 2
+    then 1 then 0: each takes the first and last plane along its axis
+    from every tile of the previous pass's output, pulls them row-wise
+    from the neighbour, zeroes them where there is none, and
+    concatenates them on either side.  Later passes copy faces that
+    already carry the earlier passes' halos, so edges and corners land
+    too, and a halo cell is non-zero only where the neighbour along
+    every axis it crosses exists: exactly the zero border beyond the
+    padded field.  Only dense slices, selects, concatenates and gathers
+    of whole planes — no per-cell index.
+    """
+    def pull(planes, col):
+        """Row ``col[i]`` of ``planes`` for each tile i, 0 where -1."""
+        got = jnp.take(planes, col, axis=0, mode="clip")
+        return jnp.where(_live(col), got, 0)
+
+    x = jnp.where(_live(nbr[:, halo.SELF]), cur, 0)
+    for a in (3, 2, 1):   # array axes of tile axes 2, 1, 0
+        n = x.shape[a]
+        lo = pull(jax.lax.slice_in_dim(x, n - 1, n, axis=a),
+                  nbr[:, halo.LO[a - 1]])
+        hi = pull(jax.lax.slice_in_dim(x, 0, 1, axis=a),
+                  nbr[:, halo.HI[a - 1]])
+        x = jnp.concatenate([lo, x, hi], axis=a)
+    return x
+
+
+def _live(col: jnp.ndarray) -> jnp.ndarray:
+    """(C,) tile ids -> (C, 1, 1, 1) mask of the ones that exist."""
+    return (col >= 0)[:, None, None, None]
+
+
 def _local_solve_jacobi(sub_m, flags_m, c: int, max_iters: int):
     """Tile-local Jacobi solve on the merged layout, halos fixed.
     Returns ``(solved merged state, last_changed_sweep (C,) int32)`` —
@@ -153,38 +191,41 @@ def _relax_merged(sub_m, flags_m):
 
 
 # How many sweeps a jnp schedule runs between halo refreshes.  The
-# gather is cheap (one take over the resident interiors), so a small cap
-# keeps total sweeps pinned near the global chain length: unbounded
-# local convergence re-propagates snaking in-tile chains after every
-# halo update (measured ~3x the sweeps of the legacy global schedule),
-# while cap 1 pays a gather per sweep.  8 amortizes the gather to noise
-# with <10% extra sweeps on the paper fields.  The Pallas blockwise
-# schedule intentionally ignores the cap: its tile lives in VMEM, where
-# iterating to full local convergence is the whole point (§IV-D).
+# refresh is cheap (``_halo_build``: dense plane copies, about one pass
+# over the haloed batch), so a small cap keeps total sweeps pinned near
+# the global chain length: unbounded local convergence re-propagates
+# snaking in-tile chains after every halo update (measured ~3x the
+# sweeps of the legacy global schedule), while cap 1 pays a refresh per
+# sweep.  8 amortizes the refresh to noise with <10% extra sweeps on the
+# paper fields.  The Pallas blockwise schedule intentionally ignores the
+# cap: its tile lives in VMEM, where iterating to full local convergence
+# is the whole point (§IV-D).
 ROUND_SWEEP_CAP = 8
 
 
-def _resident_solve(flags, idx_m, mask_m, solver: str, interpret: bool,
+def _resident_solve(flags, nbr, solver: str, interpret: bool,
                     local_max_iters: int, max_rounds, sub0=None):
     """Subbin least fixed point over a resident tile batch.
 
-    Rounds alternate (a) one gather that rebuilds every tile's haloed
-    view from the *current* interiors via the precomputed neighbor-index
-    table and (b) a tile-local solve to local convergence.  Round 1 sees
-    all-zero halos, so it reproduces a per-tile frontend solve; the
-    loop exits when a full round moves nothing, which by monotonicity is
-    exactly the global least fixed point (docs/engine.md).
+    Rounds alternate (a) a halo build that rebuilds every tile's haloed
+    view from the *current* interiors via the per-tile face-neighbour
+    table ``nbr`` (``_halo_build``: three passes of whole-plane copies,
+    about one pass over the haloed batch) and (b) a tile-local solve to
+    local convergence.  Round 1 sees all-zero halos, so it reproduces a
+    per-tile frontend solve; the loop exits when a full round moves
+    nothing, which by monotonicity is exactly the global least fixed
+    point (docs/engine.md).
 
     Subbins are computed in int32 throughout: a chain cannot exceed the
-    field's point count, and fields are < 2^31 points (enforced by the
-    int32 halo-index table), so values are identical to an int64 solve.
+    group's cell count, and groups are < 2^31 cells (enforced by
+    ``halo.group_neighbors``), so values are identical to an int64 solve.
 
     ``sub0`` overrides the all-zero initial state: the adaptive-eb path
     seeds it with the *biased-ordered decoded base* of every cell (see
     ``resident_frontend_adaptive``) and runs the same monotone relax in
     that absolute space — unsigned bias makes 0 the global minimum, so
-    the relax's ``max(cur, 0)`` neutral and the zero-filled halo gather
-    below stay correct unchanged.
+    the relax's ``max(cur, 0)`` neutral and the zero-filled halo build
+    stay correct unchanged.
 
     Returns (interiors (C, *t) in sub0's dtype, local1 (C,),
     last_round (C,)): per-tile sweeps of the first local solve, and the
@@ -202,15 +243,12 @@ def _resident_solve(flags, idx_m, mask_m, solver: str, interpret: bool,
 
     cap_iters = min(ROUND_SWEEP_CAP, local_max_iters)
 
-    def local_solve(haloed_m):
+    def local_solve(haloed):
         if blockwise:
             from ..kernels import subbin_sweep  # lazy: pallas import
 
-            h0 = haloed_m.shape[0] // c
-            return subbin_sweep.solve_tiles_blockwise(
-                haloed_m.reshape(c, h0, *haloed_m.shape[1:]), flags,
-                interpret=interpret,
-            )
+            return subbin_sweep.solve_tiles_blockwise(haloed, flags,
+                                                      interpret=interpret)
         # "frontier" runs the jacobi schedule here: with capped sweeps
         # per round, the dense worklist's active mask provably never
         # suppresses an update (a cell only moves when a needed neighbor
@@ -218,7 +256,8 @@ def _resident_solve(flags, idx_m, mask_m, solver: str, interpret: bool,
         # identical work plus 14 shifted-mask ops per sweep.  The true
         # dense-worklist reference schedule lives in core.subbin for the
         # whole-field path.
-        solved_m, last = _local_solve_jacobi(haloed_m, flags_m, c, cap_iters)
+        solved_m, last = _local_solve_jacobi(_merge(haloed), flags_m, c,
+                                             cap_iters)
         return _split_interior(solved_m, c), last
 
     def cond(s):
@@ -226,8 +265,7 @@ def _resident_solve(flags, idx_m, mask_m, solver: str, interpret: bool,
 
     def body(s):
         cur, _, rnd, local1, last_round = s
-        haloed_m = jnp.where(mask_m, cur.reshape(-1)[idx_m], 0)
-        new, iters = local_solve(haloed_m)
+        new, iters = local_solve(_halo_build(cur, nbr))
         ch_t = jnp.any((new != cur).reshape(c, -1), axis=1)
         local1 = jnp.where(rnd == 1, iters, local1)
         last_round = jnp.where(ch_t, rnd.astype(jnp.int32), last_round)
@@ -388,7 +426,7 @@ def resident_frontend(x_h, eps, dtype, preserve_order: bool):
 def _bias_ordered(o: jnp.ndarray) -> jnp.ndarray:
     """Signed ordered ints -> unsigned biased (order-preserving) space
     where 0 is the global minimum — the neutral element the relax's
-    ``max(cur, 0)`` and the zero-filled halo gather assume."""
+    ``max(cur, 0)`` and the zero-filled halo build assume."""
     ut = jnp.dtype(jnp.dtype(o.dtype).str.replace("i", "u"))
     bias = ut.type(ut.type(1) << ut.type(8 * ut.itemsize - 1))
     return o.astype(ut) ^ bias
@@ -441,13 +479,13 @@ def _ordered_delta(u_final, u_init):
 
 
 @partial(jax.jit, static_argnames=("solver", "interpret", "local_max_iters"))
-def resident_solve(flags, idx, mask, max_rounds, solver: str,
+def resident_solve(flags, nbr, max_rounds, solver: str,
                    interpret: bool, local_max_iters: int, sub0=None):
     """Jitted wrapper of the halo-round solve (see _resident_solve).
     ``max_rounds`` is traced, so it never forces a retrace."""
     TRACE_COUNTS.add("resident_solve")
-    return _resident_solve(flags, _merge(idx), _merge(mask), solver,
-                           interpret, local_max_iters, max_rounds, sub0)
+    return _resident_solve(flags, nbr, solver, interpret, local_max_iters,
+                           max_rounds, sub0)
 
 
 @partial(jax.jit, static_argnames=("chunk_len", "transform"))
@@ -551,7 +589,7 @@ def compact_streams(bitmap, words):
     return keepmap, kept_dense, words_dense, totals
 
 
-def resident_compress(x_h, eps, idx, mask, max_rounds, dtype,
+def resident_compress(x_h, eps, nbr, max_rounds, dtype,
                       preserve_order: bool, solver: str, interpret: bool,
                       local_max_iters: int, bins_store, bins_chunk: int,
                       encode_fused: bool = False, adaptive: bool = False):
@@ -588,7 +626,7 @@ def resident_compress(x_h, eps, idx, mask, max_rounds, dtype,
             "delta"
         )
         u_final, local1, last_round = resident_solve(
-            flags, idx, mask, max_rounds, solver=solver,
+            flags, nbr, max_rounds, solver=solver,
             interpret=interpret, local_max_iters=local_max_iters,
             sub0=u_init,
         )
@@ -603,7 +641,7 @@ def resident_compress(x_h, eps, idx, mask, max_rounds, dtype,
         zc = jnp.zeros((capacity,), jnp.int32)
         return bins_streams, None, zc, zc, None
     sub, local1, last_round = resident_solve(
-        flags, idx, mask, max_rounds, solver=solver, interpret=interpret,
+        flags, nbr, max_rounds, solver=solver, interpret=interpret,
         local_max_iters=local_max_iters,
     )
     return bins_streams, sub, local1, last_round, _sub_max(sub)

@@ -16,7 +16,7 @@ Transfer accounting
 makes, by category:
 
   h2d_tiles      field-tile uploads (one per compress group)
-  h2d_aux        small operands: eps vector + halo index tables
+  h2d_aux        small operands: eps vector + halo neighbour table
   d2h_aux        tiny mid-pipeline fetches: the sub-max scalar (subbin
                  width pick, at the solve's natural sync point) and the
                  fused path's compacted-stream totals
@@ -88,7 +88,8 @@ _CHUNK_WORDS = {2: 8192, 4: 4096, 8: 2048}  # word bytes -> words / 16 KiB
 # section header, so readers never guess): bins pick theirs host-side
 # from the value-range bound (engine._store_bin_dtype); subbins pick
 # int16 when the solved maximum fits, else int32 — values are < 2^31 by
-# the int32 halo-index guard, so the legacy int64 width is never needed.
+# the group-size guard of ``halo.group_neighbors``, so the legacy int64
+# width is never needed.
 # Every halved width halves the chunk rows and bit-planes of the
 # dominant BIT/RZE stage on both ends of the pipeline.
 
@@ -317,7 +318,7 @@ class Executor:
             n_chunk = r1 - r0
             capacity = resident_capacity(n_chunk, floor)
             with span("exec.pack", tiles=n_chunk, capacity=capacity):
-                idx, mask = halo.group_index(layouts[lo:hi], capacity)
+                nbr = halo.group_neighbors(layouts[lo:hi], capacity)
                 xc, ec = x_tiles[r0:r1], eps_tiles[r0:r1]
                 pad = capacity - n_chunk
                 if pad:
@@ -327,23 +328,21 @@ class Executor:
                     ec = np.concatenate([ec, np.ones(pad, np.float64)])
             TRANSFER_COUNTS.add("h2d_tiles")
             TRANSFER_COUNTS.add("bytes_h2d", xc.nbytes)
-            TRANSFER_COUNTS.add("h2d_aux", 3)
-            TRANSFER_COUNTS.add("bytes_h2d",
-                                ec.nbytes + idx.nbytes + mask.nbytes)
+            TRANSFER_COUNTS.add("h2d_aux", 2)
+            TRANSFER_COUNTS.add("bytes_h2d", ec.nbytes + nbr.nbytes)
             with span("exec.upload", tiles=n_chunk, capacity=capacity,
                       nbytes=xc.nbytes):
                 x_dev = self.put(xc)
                 eps_dev = device.put_eps(self.put, ec)
-                idx_dev = self.put(idx)
-                mask_dev = self.put(mask)
-                fence(x_dev, eps_dev, idx_dev, mask_dev)
+                nbr_dev = self.put(nbr)
+                fence(x_dev, eps_dev, nbr_dev)
             max_rounds = jnp.asarray(n_chunk * layout0.tile_elems + 2,
                                      jnp.int64)
             with span("exec.solve", tiles=n_chunk, capacity=capacity,
                       solver=solver, adaptive=adaptive):
                 bins_s, sub_dev, local1, last_round, sub_max = \
                     device.resident_compress(
-                        x_dev, eps_dev, idx_dev, mask_dev, max_rounds,
+                        x_dev, eps_dev, nbr_dev, max_rounds,
                         dtype=jnp.dtype(dtype), preserve_order=preserve_order,
                         solver=solver, interpret=interpret,
                         local_max_iters=layout0.tile_elems + 2,

@@ -1,34 +1,32 @@
-"""Device-side halo exchange: precomputed neighbor-index tables.
+"""Device-side halo exchange: per-tile face-neighbour tables.
 
-The PR-1 engine refreshed tile halos on the host: every relax round
-gathered tile interiors back to numpy, scattered them into a padded
-whole-field array, and re-extracted haloed tiles (two full-field copies
-plus a device round-trip *per round per field*).  This module replaces
-that with a one-gather formulation that keeps the solve device-resident:
+The solve's halo rounds rebuild every tile's haloed view from the
+current interiors of a resident batch (``device._halo_build``).  All it
+needs to know is which tile lies across each face: a
+:class:`~repro.engine.plan.TileLayout`'s padded extent is exactly
+``grid × tile``, so a halo cell lies outside the padded field (the zero
+border the legacy host path materialized) exactly where the
+neighbouring tile does not exist.  Every halo cell is a face, edge or
+corner of one of the 26 neighbouring tiles, and an edge or corner is
+reached by following face neighbours one axis at a time, so a table of
+six face-neighbour tile ids per tile is the whole plan constant.
 
-For a :class:`~repro.engine.plan.TileLayout` we precompute, once per
-layout, a flat index table ``idx`` and validity mask ``mask`` of shape
-``(n_tiles, *halo_tile)`` such that for interiors ``I`` of shape
-``(n_tiles, *tile)``::
+Table layout, one int32 row per tile::
 
-    haloed = where(mask, I.reshape(-1)[idx], 0)
+    (lo0, hi0, lo1, hi1, lo2, hi2, self)
 
-reproduces exactly what host-side ``scatter_interiors`` +
-``extract_halo_tiles`` produced: interior cells map to themselves, halo
-cells map to the adjacent tile's interior, and cells beyond the padded
-field (the zero border the legacy path materialized) are masked to 0.
-One gather per relax round, no host involvement.
+the tile ids of the low and high face neighbour along axes 0, 1 and 2,
+``-1`` where there is none, and the tile's own id, ``-1`` on the pad
+rows that fill a group up to its resident capacity (their interiors
+read 0, so pad tiles stay at the zero state).
 
 Group tables: a compress group holds the concatenated tiles of several
 fields.  Fields are independent (halos never cross fields), so the group
 table is each field's table shifted by its tile offset, padded with
-masked rows up to the group's resident capacity.  Tables depend only on
+dead rows up to the group's resident capacity.  Tables depend only on
 (layout sequence, capacity), so steady-state serving reuses them from an
-LRU cache — they are plan constants, not per-request data.
-
-Index dtype is int32: a resident group would need > 2^31 interior cells
-before overflow (≈ 8 GiB of int32 subbins), far beyond a sane resident
-set; guarded by an explicit check.
+LRU cache — they are plan constants, not per-request data, and take 28
+bytes a tile (57 KB at capacity 2,048).
 """
 from __future__ import annotations
 
@@ -36,92 +34,57 @@ from functools import lru_cache
 
 import numpy as np
 
-from .plan import HALO, TileLayout
+from .plan import TileLayout
 
+# Columns of a neighbour table: face neighbours, then the tile itself.
+LO = (0, 2, 4)
+HI = (1, 3, 5)
+SELF = 6
+N_COLS = 7
 
-# Cached tables are field-sized (an int32 index plus a bool mask over
-# every haloed cell, ~2x the field's own bytes for f32 data), so the
-# caches are kept deliberately small: entry-count eviction cannot bound
-# bytes, and a serving process that churns through many distinct large
-# field shapes should expect roughly <maxsize> x <largest field> bytes
-# of steady-state table residency (call .cache_clear() to drop it).
 
 @lru_cache(maxsize=32)
-def neighbor_index(layout: TileLayout) -> tuple[np.ndarray, np.ndarray]:
-    """-> (idx int32, mask bool), both shaped (n_tiles, *halo_tile).
-
-    ``idx`` indexes the flattened ``(n_tiles, *tile)`` interior array;
-    ``mask`` is False where the haloed cell falls outside the padded
-    field (reads there must yield the zero border).
-    """
-    t, g, p = layout.tile, layout.grid, layout.padded
-    # Per axis: global padded coordinate of every (grid pos, halo-local)
-    # pair, then its (tile grid index, in-tile index) decomposition.
-    ax = []
+def neighbor_tiles(layout: TileLayout) -> np.ndarray:
+    """-> int32 (n_tiles, 7): face-neighbour tile ids of each tile (row-
+    major grid order) along axes 0, 1, 2, low then high, ``-1`` at the
+    padded field's faces, and the tile's own id."""
+    g = layout.grid
+    ids = np.arange(layout.n_tiles, dtype=np.int64).reshape(g)
+    out = np.full(g + (N_COLS,), -1, np.int64)
     for a in range(3):
-        coord = (np.arange(g[a])[:, None] * t[a] - HALO
-                 + np.arange(t[a] + 2 * HALO)[None, :])        # (g_a, h_a)
-        valid = (coord >= 0) & (coord < p[a])
-        ti, li = np.divmod(np.clip(coord, 0, p[a] - 1), t[a])
-        ax.append((ti, li, valid))
-    # Broadcast the three axes over (g0, h0, g1, h1, g2, h2).
-    ti0 = ax[0][0].reshape(g[0], t[0] + 2, 1, 1, 1, 1)
-    li0 = ax[0][1].reshape(g[0], t[0] + 2, 1, 1, 1, 1)
-    v0 = ax[0][2].reshape(g[0], t[0] + 2, 1, 1, 1, 1)
-    ti1 = ax[1][0].reshape(1, 1, g[1], t[1] + 2, 1, 1)
-    li1 = ax[1][1].reshape(1, 1, g[1], t[1] + 2, 1, 1)
-    v1 = ax[1][2].reshape(1, 1, g[1], t[1] + 2, 1, 1)
-    ti2 = ax[2][0].reshape(1, 1, 1, 1, g[2], t[2] + 2)
-    li2 = ax[2][1].reshape(1, 1, 1, 1, g[2], t[2] + 2)
-    v2 = ax[2][2].reshape(1, 1, 1, 1, g[2], t[2] + 2)
-
-    tile_id = (ti0 * g[1] + ti1) * g[2] + ti2
-    flat = ((tile_id * t[0] + li0) * t[1] + li1) * t[2] + li2
-    mask = v0 & v1 & v2
-    if layout.n_tiles * layout.tile_elems > np.iinfo(np.int32).max:
-        raise ValueError("field too large for an int32 halo index table")
-    # (g0, h0, g1, h1, g2, h2) -> (n_tiles, h0, h1, h2)
-    order = (0, 2, 4, 1, 3, 5)
-    h = layout.halo_tile
-    idx = np.ascontiguousarray(
-        np.transpose(flat, order).reshape((layout.n_tiles,) + h)
-    ).astype(np.int32)
-    mask = np.ascontiguousarray(
-        np.transpose(np.broadcast_to(mask, flat.shape), order)
-        .reshape((layout.n_tiles,) + h)
-    )
-    return idx, mask
+        lo = [slice(None)] * 3
+        hi = [slice(None)] * 3
+        lo[a], hi[a] = slice(1, None), slice(None, -1)
+        # the low neighbour of tile i along a is tile i - 1 there
+        out[tuple(lo) + (LO[a],)] = ids[tuple(hi)]
+        out[tuple(hi) + (HI[a],)] = ids[tuple(lo)]
+    out[..., SELF] = ids
+    return out.reshape(layout.n_tiles, N_COLS).astype(np.int32)
 
 
 @lru_cache(maxsize=32)
-def group_index(layouts: tuple[TileLayout, ...], capacity: int):
-    """Concatenated per-field tables padded to ``capacity`` tiles.
+def group_neighbors(layouts: tuple[TileLayout, ...],
+                    capacity: int) -> np.ndarray:
+    """Concatenated per-field tables padded to ``capacity`` rows.
 
     All layouts in a group share one tile shape (the engine groups by
-    it); each field's indices are shifted by its tile offset so the
-    gather never crosses fields.  Pad rows are fully masked: pad tiles
-    read the zero border everywhere, which keeps their subbins at 0.
+    it); each field's ids are shifted by its tile offset so the build
+    never crosses fields.  Pad rows are all ``-1``: pad tiles read 0
+    everywhere, which keeps their subbins at 0.  The solve keeps subbins
+    in int32, so a group is refused past 2^31 cells.
     """
     tile = layouts[0].tile
-    h = layouts[0].halo_tile
-    elems = layouts[0].tile_elems
-    idxs, masks = [], []
+    tables = []
     off = 0
     for lay in layouts:
         if lay.tile != tile:
             raise ValueError("group layouts must share one tile shape")
-        idx, mask = lay.neighbor_index()
-        idxs.append(idx + np.int64(off) * elems)
-        masks.append(mask)
+        t = neighbor_tiles(lay)
+        tables.append(np.where(t >= 0, t + off, -1))
         off += lay.n_tiles
     if off > capacity:
         raise ValueError(f"group of {off} tiles exceeds capacity {capacity}")
-    if capacity * elems > np.iinfo(np.int32).max:
-        raise ValueError("resident group too large for an int32 index table")
-    pad = capacity - off
-    if pad:
-        idxs.append(np.zeros((pad,) + h, np.int64))
-        masks.append(np.zeros((pad,) + h, bool))
-    idx = np.ascontiguousarray(np.concatenate(idxs)).astype(np.int32)
-    mask = np.ascontiguousarray(np.concatenate(masks))
-    return idx, mask
+    if capacity * layouts[0].tile_elems > np.iinfo(np.int32).max:
+        raise ValueError("resident group too large for int32 subbins")
+    tables.append(np.full((capacity - off, N_COLS), -1, np.int32))
+    return np.ascontiguousarray(np.concatenate(tables), dtype=np.int32)

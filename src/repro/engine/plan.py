@@ -83,14 +83,6 @@ class TileLayout:
     def halo_tile(self) -> tuple[int, int, int]:
         return tuple(t + 2 * HALO for t in self.tile)
 
-    def neighbor_index(self):
-        """Flat gather table rebuilding haloed tiles from interiors on
-        device -> (idx int32, mask bool), both (n_tiles, *halo_tile).
-        See engine/halo.py; cached per layout."""
-        from . import halo  # lazy: halo imports this module
-
-        return halo.neighbor_index(self)
-
 
 @dataclass(frozen=True)
 class CompressionPlan:

@@ -381,13 +381,13 @@ def _compress_chain_step(members, t, kind, store, dtype, preserve_order,
     subs_cpt = 0
     if preserve_order:
         layouts = tuple(r.layout for r in members)
-        idx, mask = halo.group_index(layouts, capacity)
-        TRANSFER_COUNTS.add("h2d_aux", 2)
-        TRANSFER_COUNTS.add("bytes_h2d", idx.nbytes + mask.nbytes)
-        idx_dev, mask_dev = put(idx), put(mask)
+        nbr = halo.group_neighbors(layouts, capacity)
+        TRANSFER_COUNTS.add("h2d_aux")
+        TRANSFER_COUNTS.add("bytes_h2d", nbr.nbytes)
+        nbr_dev = put(nbr)
         max_rounds = jnp.asarray(n_total * layout0.tile_elems + 2, jnp.int64)
         sub, local1, last_round = device.resident_solve(
-            flags, idx_dev, mask_dev, max_rounds, solver=solver_c,
+            flags, nbr_dev, max_rounds, solver=solver_c,
             interpret=interpret, local_max_iters=layout0.tile_elems + 2,
             sub0=u_init,
         )

@@ -41,14 +41,14 @@ def _bench():
             "miranda": {
                 "engine": {"ratio": 11.125, "compress_mbps": 5.0},
                 "transfers_per_compress": {
-                    "h2d_tiles": 1.0, "h2d_aux": 3.0,
+                    "h2d_tiles": 1.0, "h2d_aux": 2.0,
                     "d2h_aux": 1.0, "d2h_sections": 1.0,
                 },
             },
             "isabel": {
                 "engine": {"ratio": 5.039, "compress_mbps": 20.0},
                 "transfers_per_compress": {
-                    "h2d_tiles": 1.0, "h2d_aux": 3.0,
+                    "h2d_tiles": 1.0, "h2d_aux": 2.0,
                     "d2h_aux": 1.0, "d2h_sections": 1.0,
                 },
             },

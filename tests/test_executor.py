@@ -53,6 +53,23 @@ def test_one_upload_one_download_per_compress_group(rng):
     assert executor.TRANSFER_COUNTS["d2h_values"] == 1
 
 
+def test_compress_aux_upload_is_eps_and_neighbour_table(rng):
+    """Beside its tiles, a compress group uploads the f64 eps vector and
+    the (capacity, 7) int32 halo neighbour table: per tile, not per
+    cell."""
+    from repro.engine import halo
+
+    x = rng.standard_normal((40, 36, 70))
+    layout = CompressionPlan().layout_for(x.shape)
+    capacity = executor.resident_capacity(layout.n_tiles)
+    executor.reset_transfer_counts()
+    engine.compress(x, 1e-2)
+    assert executor.TRANSFER_COUNTS["h2d_aux"] == 2
+    tiles = capacity * int(np.prod(layout.halo_tile)) * x.itemsize
+    aux = capacity * (8 + 4 * halo.N_COLS)
+    assert executor.TRANSFER_COUNTS["bytes_h2d"] == tiles + aux
+
+
 # ---------------------------------------------------------- trace probe
 
 def test_resident_traces_constant_across_mixed_shapes_dtypes(rng):

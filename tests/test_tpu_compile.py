@@ -19,7 +19,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.quantize import Eps
-from repro.engine import device
+from repro.engine import device, halo
 from repro.kernels import fused_decode, fused_encode, subbin_sweep
 
 TILE = (16, 16, 64)
@@ -100,19 +100,19 @@ def test_fused_decode_compiles(spec, bins_word, subs_word):
         *streams(bins_word), *streams(subs_word), _eps(spec, BATCH))
 
 
-def _resident_compress(x, e, i, m, r, encode_fused):
+def _resident_compress(x, e, n, r, encode_fused):
     return device.resident_compress(
-        x, e, i, m, r, jnp.dtype("float32"), True, "blockwise", False,
+        x, e, n, r, jnp.dtype("float32"), True, "blockwise", False,
         ELEMS + 2, jnp.dtype("int16"), 8192, encode_fused=encode_fused)
 
 
 def _compress_args(make, eps):
     return (make((BATCH,) + HALO, jnp.float32), eps,
-            make((BATCH,) + HALO, jnp.int32), make((BATCH,) + HALO, jnp.bool_))
+            make((BATCH, halo.N_COLS), jnp.int32))
 
 
 def test_staged_resident_compress_compiles(spec):
-    _compile(lambda x, e, i, m, r: _resident_compress(x, e, i, m, r, False),
+    _compile(lambda x, e, n, r: _resident_compress(x, e, n, r, False),
              *_compress_args(spec, _eps(spec, BATCH)), spec((), jnp.int64))
 
 
@@ -130,7 +130,7 @@ def test_sharded_compress_compiles_on_four_chips(topo, spec):
     tiles = NamedSharding(mesh, P("data"))
     with jax.set_mesh(mesh):
         compiled = _compile(
-            lambda x, e, i, m, r: _resident_compress(x, e, i, m, r, True),
+            lambda x, e, n, r: _resident_compress(x, e, n, r, True),
             *_compress_args(partial(spec, sharding=tiles),
                             _eps(spec, BATCH, sharding=tiles)),
             spec((), jnp.int64, sharding=NamedSharding(mesh, P())))
